@@ -17,6 +17,8 @@ os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=4")
 
 import tempfile  # noqa: E402
 
+import jax  # noqa: E402
+
 from repro import configs  # noqa: E402
 from repro.configs.base import TrainConfig  # noqa: E402
 from repro.core import FlowsService, RealClock  # noqa: E402
@@ -40,6 +42,10 @@ def main():
         ckpt_dir=os.path.join(workdir, "ckpt"),
         mesh=big_mesh,
     )
+    # the state is created already sharded: each device holds a quarter of
+    # the largest weight, and no device ever held all of it
+    big = max(jax.tree_util.tree_leaves(fabric.state.params), key=lambda x: x.size)
+    assert all(s.data.size * 4 == big.size for s in big.addressable_shards)
     fabric.save_checkpoint()
     fabric.inject_failure_at = 6  # devices "die" during the second segment
 

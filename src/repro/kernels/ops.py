@@ -1,8 +1,9 @@
-"""Jit'd public wrappers over the Pallas kernels.
+"""Jit'd public wrappers over the Pallas kernels, as the models call them.
 
-On a machine without TPUs the kernels run in ``interpret=True`` mode (the
-kernel body executes in Python on CPU) — numerically identical, so the same
-tests validate what will run compiled on TPU.
+The kernels compile for the TPU.  Nothing here falls back to interpret
+mode: on a host without a TPU a call fails, unless the caller asked for
+interpretation itself (the kernel tests do, with ``interpret=True`` or
+``pltpu.force_tpu_interpret_mode()``).
 """
 
 from __future__ import annotations
@@ -14,40 +15,46 @@ from repro.kernels import flash_attention as _fa
 from repro.kernels import mamba_scan as _ms
 from repro.kernels import moe_gmm as _gmm
 
+#: sublane tiling of a TPU vreg: a block's second-to-last dimension must be
+#: a multiple of it (or the whole dimension)
+_SUBLANES = 8
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+
+def _seq_block(n: int, preferred: int) -> int:
+    """Largest block <= ``preferred`` that tiles ``n`` and the TPU accepts.
+
+    ``preferred`` (a power of two >= 8) is halved while it does not divide
+    ``n``, but never below one vreg of sublanes.
+    """
+    if n <= preferred:
+        return n
+    b = preferred
+    while n % b and b > _SUBLANES:
+        b //= 2
+    if n % b:
+        raise ValueError(
+            f"sequence length {n} has no block of {_SUBLANES}..{preferred} "
+            f"rows that tiles it; pad it to a multiple of {_SUBLANES}"
+        )
+    return b
 
 
 def flash_attention(q, k, v, causal=True, window=None, logit_softcap=None,
                     block_q=None, block_k=None):
     S, T = q.shape[1], k.shape[1]
-    bq = block_q or min(_fa.DEFAULT_BLOCK_Q, S)
-    bk = block_k or min(_fa.DEFAULT_BLOCK_K, T)
-    # shrink to a divisor if the sequence doesn't tile
-    while S % bq:
-        bq //= 2
-    while T % bk:
-        bk //= 2
     return _fa.flash_attention(
         q, k, v,
         causal=causal, window=window, logit_softcap=logit_softcap,
-        block_q=max(bq, 1), block_k=max(bk, 1),
-        interpret=_interpret(),
+        block_q=block_q or _seq_block(S, _fa.DEFAULT_BLOCK_Q),
+        block_k=block_k or _seq_block(T, _fa.DEFAULT_BLOCK_K),
     )
 
 
-def mamba_scan(xh, dt, A, Bm, Cm, chunk=None):
-    S = xh.shape[1]
-    c = chunk or min(_ms.DEFAULT_CHUNK, S)
-    while S % c:
-        c //= 2
-    return _ms.mamba_scan(xh, dt, A, Bm, Cm, chunk=max(c, 1),
-                          interpret=_interpret())
-
-
-def gmm(x, w, **kw):
-    return _gmm.gmm(x, w, interpret=_interpret(), **kw)
+# The scan's chunk is the lane dimension of dt's block, so it is never
+# shrunk to fit: it tiles S exactly or S is one chunk (the kernel raises
+# otherwise).  The grouped matmul's blocks are fixed MXU tiles.
+mamba_scan = _ms.mamba_scan
+gmm = _gmm.gmm
 
 
 def moe_expert_mlp(expert_in: jnp.ndarray, experts: dict, cfg) -> jnp.ndarray:

@@ -110,6 +110,9 @@ def run_cell(
         "mesh": mesh_kind,
         "mesh_shape": list(mesh.devices.shape),
         "chips": mesh_chip_count(mesh),
+        # the kind actually compiled for; roofline.analyze refuses kinds
+        # without published peaks, such as the placeholder host devices
+        "device_kind": mesh.devices.flat[0].device_kind,
         "kind": shape.kind,
         "seq_len": shape.seq_len,
         "global_batch": shape.global_batch,
@@ -147,7 +150,7 @@ def run_cell(
                     record.setdefault("memory", {})[key] = getattr(
                         mem, key, None
                     )
-            cost = hlo_mod.cost_analysis_dict(compiled)
+            cost = compiled.cost_analysis()
             if cost:
                 record["cost"] = {
                     k: cost[k]

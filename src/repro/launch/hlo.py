@@ -17,16 +17,6 @@ import re
 from collections import defaultdict
 
 
-def cost_analysis_dict(compiled) -> dict:
-    """Normalize ``Compiled.cost_analysis()`` across jax versions.
-
-    Older jax returns a one-element list of dicts; newer returns the dict.
-    """
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost or {}
-
 DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2,
     "s64": 8, "s32": 4, "s16": 2, "s8": 1,

@@ -10,32 +10,19 @@ single device.
 from __future__ import annotations
 
 import jax
-
-#: TPU v5e hardware constants (per chip) used by the roofline analysis
-PEAK_FLOPS_BF16 = 197e12        # FLOP/s
-HBM_BANDWIDTH = 819e9           # B/s
-ICI_LINK_BANDWIDTH = 50e9       # B/s per link
-
-
-def _axis_type_kwargs(n: int) -> dict:
-    # jax.sharding.AxisType landed in jax 0.5; older versions have neither
-    # the enum nor the make_mesh(axis_types=...) parameter.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n}
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
     """Arbitrary mesh helper (tests, elastic rescale demos)."""
     return jax.make_mesh(tuple(shape), tuple(axes),
-                         **_axis_type_kwargs(len(axes)))
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def mesh_chip_count(mesh) -> int:

@@ -1,10 +1,11 @@
 """Roofline analysis over dry-run records (EXPERIMENTS.md §Roofline).
 
-Three terms per (arch × shape × mesh), all in seconds-per-step:
+Three terms per (arch × shape × mesh), all in seconds-per-step, against
+the peaks of the record's ``device_kind`` (:mod:`repro.launch.chips`):
 
-    compute    = HLO_FLOPs_per_device / peak_FLOP/s          (197e12 bf16)
-    memory     = HLO_bytes_per_device / HBM_bandwidth        (819e9 B/s)
-    collective = wire_bytes_per_device / ICI_link_bandwidth  (50e9 B/s)
+    compute    = HLO_FLOPs_per_device / peak bf16 FLOP/s
+    memory     = HLO_bytes_per_device / HBM bandwidth
+    collective = wire_bytes_per_device / ICI link bandwidth
 
 plus MODEL_FLOPS (6·N·D train / 2·N·D serve; N_active for MoE), the
 useful-compute ratio MODEL_FLOPS / (chips·HLO_FLOPs), and the roofline
@@ -23,11 +24,7 @@ import glob
 import json
 import os
 
-from repro.launch.mesh import (
-    HBM_BANDWIDTH,
-    ICI_LINK_BANDWIDTH,
-    PEAK_FLOPS_BF16,
-)
+from repro.launch.chips import peaks
 
 RESULTS_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
@@ -50,6 +47,7 @@ def model_flops(record: dict) -> float:
 
 def analyze(record: dict) -> dict:
     chips = record["chips"]
+    peak = peaks(record["device_kind"])
     flops_dev = record.get("cost", {}).get("flops", 0.0)
     bytes_dev = record.get("cost", {}).get("bytes accessed", 0.0)
     wire_dev = (
@@ -57,11 +55,11 @@ def analyze(record: dict) -> dict:
         .get("_total", {})
         .get("wire_bytes_per_device", 0)
     )
-    compute_t = flops_dev / PEAK_FLOPS_BF16
-    memory_t = bytes_dev / HBM_BANDWIDTH
-    coll_t = wire_dev / ICI_LINK_BANDWIDTH
+    compute_t = flops_dev / peak.bf16_flops
+    memory_t = bytes_dev / peak.hbm_bytes_per_s
+    coll_t = wire_dev / peak.ici_link_bytes_per_s
     mf = model_flops(record)
-    ideal_t = mf / (chips * PEAK_FLOPS_BF16)
+    ideal_t = mf / (chips * peak.bf16_flops)
     terms = {"compute": compute_t, "memory": memory_t, "collective": coll_t}
     dominant = max(terms, key=terms.get)
     bound_t = max(terms.values()) if max(terms.values()) > 0 else float("inf")

@@ -10,8 +10,9 @@ paper's error-routing semantics applied to an ML job.
     PYTHONPATH=src python -m repro.launch.train --arch internlm2-1.8b \
         --smoke --segments 3 --steps-per-segment 5 --simulate-failure
 
-On a CPU container this runs the reduced (smoke) configs; the same driver
-with ``--mesh dxm`` shards over whatever devices JAX sees.
+``--smoke`` (the default) runs the reduced config, ``--full`` the published
+one; ``--mesh 2x2`` shards the state over a data x model mesh of that shape
+(the product must be the number of devices JAX sees).
 """
 
 from __future__ import annotations
@@ -33,14 +34,18 @@ from repro.core.providers import (
     SearchProvider,
     TransferProvider,
 )
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_mesh
 from repro.train.fabric import TrainingFabric
 
 
-def training_flow_definition(fns: dict, eid: str, n_segments: int) -> dict:
+def training_flow_definition(fns: dict, eid: str, n_segments: int,
+                             steps_per_segment: int = 5,
+                             async_checkpoint: bool = False) -> dict:
     """The segmented training flow with failure recovery.
 
-    Stage -> [Train -> (NodeFailure? Restore -> Train)] x segments
-          -> Evaluate -> Checkpoint -> Catalog -> Notify
+    Stage -> [Train -> (NodeFailure? Restore -> Train) -> Checkpoint]
+             x segments -> Evaluate -> Catalog -> Notify
     """
     compute = lambda fid, kwargs: {  # noqa: E731
         "Type": "Action",
@@ -58,7 +63,7 @@ def training_flow_definition(fns: dict, eid: str, n_segments: int) -> dict:
             "Next": "Train",
         },
         "Train": {
-            **compute(fns["train_steps"], {}),
+            **compute(fns["train_steps"], {"n_steps": steps_per_segment}),
             "ResultPath": "$.train",
             "WaitTime": 3600,
             "Catch": [
@@ -76,7 +81,8 @@ def training_flow_definition(fns: dict, eid: str, n_segments: int) -> dict:
             "Next": "Train",
         },
         "Checkpoint": {
-            **compute(fns["save_checkpoint"], {}),
+            **compute(fns["save_checkpoint"],
+                      {"synchronous": not async_checkpoint}),
             "ResultPath": "$.checkpoint",
             "Next": "NextSegment",
         },
@@ -164,11 +170,69 @@ def build_stack(workdir: str, clock=None):
     return flows, compute
 
 
+def run_training_flow(fabric: TrainingFabric, *, workdir: str, segments: int,
+                      steps_per_segment: int, label: str,
+                      restore=None, async_checkpoint: bool = False,
+                      timeout: float = 3600):
+    """Publish the training flow over ``fabric`` and run it to its end.
+
+    ``restore`` (a no-argument callable) replaces ``fabric.restore_latest``
+    as the Restore state's function, e.g. a reshard onto another mesh.
+    Returns the finished run.
+    """
+    flows, compute = build_stack(workdir)
+    reg = fabric.register_all(compute)
+    fns = dict(reg["functions"])
+    fns["_increment"] = compute.register_function(
+        lambda segment: segment + 1, name="increment"
+    )
+    if restore is not None:
+        fns["restore_latest"] = compute.register_function(
+            restore, name="restore")
+    definition = training_flow_definition(
+        fns, reg["endpoint_id"], segments,
+        steps_per_segment=steps_per_segment,
+        async_checkpoint=async_checkpoint,
+    )
+    arch = fabric.model_cfg.arch
+    record = flows.publish_flow(
+        definition,
+        input_schema={"type": "object"},
+        title=f"Train {arch}",
+        keywords=["training", arch],
+    )
+    run = flows.run_flow(
+        record.flow_id,
+        {
+            "run_label": label,
+            "notify_values": {"label": label, "loss": "(see catalog)"},
+        },
+        label=label,
+    )
+    try:
+        flows.engine.wait(run.run_id, timeout=timeout)
+    finally:
+        flows.engine.shutdown()
+    return run
+
+
+def parse_mesh(text: str) -> tuple[int, int]:
+    """``"2x2"`` -> (2, 2): data x model."""
+    try:
+        d, m = (int(v) for v in text.lower().split("x"))
+    except ValueError:
+        raise argparse.ArgumentTypeError(
+            f"--mesh wants DATAxMODEL, e.g. 2x2; got {text!r}") from None
+    return d, m
+
+
 def main() -> int:
     parser = argparse.ArgumentParser(description=__doc__)
     parser.add_argument("--arch", default="internlm2-1.8b")
     parser.add_argument("--smoke", action="store_true", default=True)
     parser.add_argument("--full", dest="smoke", action="store_false")
+    parser.add_argument("--mesh", type=parse_mesh, default=None,
+                        help="DATAxMODEL device mesh, e.g. 2x2")
     parser.add_argument("--segments", type=int, default=2)
     parser.add_argument("--steps-per-segment", type=int, default=5)
     parser.add_argument("--batch", type=int, default=4)
@@ -178,48 +242,25 @@ def main() -> int:
     parser.add_argument("--label", default="train-demo")
     args = parser.parse_args()
 
+    enable_compile_cache()
     workdir = args.workdir or tempfile.mkdtemp(prefix="repro-train-")
     cfg = configs.get(args.arch, smoke=args.smoke)
     tcfg = TrainConfig(total_steps=args.segments * args.steps_per_segment,
                        warmup_steps=2, learning_rate=1e-3)
+    mesh = make_mesh(args.mesh, ("data", "model")) if args.mesh else None
     fabric = TrainingFabric(
         cfg, tcfg, batch=args.batch, seq_len=args.seq_len,
-        ckpt_dir=os.path.join(workdir, "ckpt"),
+        ckpt_dir=os.path.join(workdir, "ckpt"), mesh=mesh,
     )
     # seed checkpoint so a failure in segment 0 can restore
     fabric.save_checkpoint()
     if args.simulate_failure:
         fabric.inject_failure_at = args.steps_per_segment + 1
 
-    flows, compute = build_stack(workdir)
-    reg = fabric.register_all(compute)
-    reg["functions"]["_increment"] = compute.register_function(
-        lambda segment: segment + 1, name="increment"
+    run = run_training_flow(
+        fabric, workdir=workdir, segments=args.segments,
+        steps_per_segment=args.steps_per_segment, label=args.label,
     )
-    fabric_fns = dict(reg["functions"])
-    # bind per-segment step counts
-    compute._functions[fabric_fns["train_steps"]].fn = (
-        lambda **kw: fabric.train_steps(n_steps=args.steps_per_segment)
-    )
-
-    definition = training_flow_definition(
-        fabric_fns, reg["endpoint_id"], args.segments
-    )
-    record = flows.publish_flow(
-        definition,
-        input_schema={"type": "object"},
-        title=f"Train {args.arch}",
-        keywords=["training", args.arch],
-    )
-    run = flows.run_flow(
-        record.flow_id,
-        {
-            "run_label": args.label,
-            "notify_values": {"label": args.label, "loss": "(see catalog)"},
-        },
-        label=args.label,
-    )
-    flows.engine.wait(run.run_id, timeout=3600)
     print(f"run {run.run_id}: {run.status}")
     if run.status != "SUCCEEDED":
         print(json.dumps(run.error, indent=1))

@@ -31,7 +31,9 @@ def _make(key, spec: ParamSpec, dtype) -> jnp.ndarray:
         return jnp.zeros(spec.shape, dtype)
     if spec.init == "ones":
         return jnp.ones(spec.shape, dtype)
-    fan_in = spec.shape[0] if len(spec.shape) > 1 else spec.shape[-1]
+    # a matrix's input dimension is its second-to-last: leading axes stack
+    # layers or experts ([L, d_in, d_out], [L, E, d_in, d_out])
+    fan_in = spec.shape[-2] if len(spec.shape) > 1 else spec.shape[-1]
     std = spec.scale / jnp.sqrt(jnp.asarray(fan_in, jnp.float32))
     return (jax.random.truncated_normal(key, -3, 3, spec.shape, jnp.float32)
             * std).astype(dtype)

@@ -53,7 +53,13 @@ class ServeEngine:
         frames: np.ndarray | None = None,     # encdec
         pixel_embeds: np.ndarray | None = None,  # vlm
     ) -> dict:
-        """Generate for a batch of equal-length prompts."""
+        """Generate for a batch of equal-length prompts.
+
+        Returns the generated ``tokens`` [B, max_new_tokens] and, kept on
+        the device for checking against a reference, ``logits``: the
+        prefill's last-position logits and those of the first decode step
+        (each [B, 1, V]).
+        """
         B, S = prompts.shape
         self.stats["requests"] += B
         self.stats["batches"] += 1
@@ -65,6 +71,7 @@ class ServeEngine:
         if pixel_embeds is not None:
             batch["pixel_embeds"] = jnp.asarray(pixel_embeds)
         logits, cache = self._jit_prefill(self.params, batch)
+        kept_logits = [logits]
         position = S
 
         out = []
@@ -83,10 +90,12 @@ class ServeEngine:
                 jnp.asarray(position, jnp.int32),
             )
             position += 1
+            if step == 0:
+                kept_logits.append(logits)
             cur = np.asarray(self._sample(logits))
         generated = np.stack(out, axis=1) if out else np.zeros((B, 0), np.int32)
         self.stats["tokens_generated"] += int(generated.size)
-        return {"tokens": generated, "prompt_len": S}
+        return {"tokens": generated, "prompt_len": S, "logits": kept_logits}
 
 
 class BatchAccumulator:

@@ -23,7 +23,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig, TrainConfig
 from repro.core.errors import NodeFailure
-from repro.models.model import Model
+from repro.models.model import Model, param_axes, param_shapes
 from repro.parallel.sharding import (
     ACT_RULES,
     PARAM_RULES,
@@ -33,6 +33,7 @@ from repro.parallel.sharding import (
 from repro.train import checkpoint as ckpt
 from repro.train.data import SyntheticTokens
 from repro.train.loop import TrainState, init_state, make_eval_step, make_train_step
+from repro.train.optimizer import AdamWState
 
 
 class TrainingFabric:
@@ -57,6 +58,7 @@ class TrainingFabric:
             model_cfg.vocab_size, batch, seq_len, seed=seed
         )
         self.model = Model(model_cfg)
+        self.axes = param_axes(model_cfg)
         self.state: TrainState | None = None
         self.history: list[dict] = []
         self.inject_failure_at: int | None = None
@@ -64,31 +66,36 @@ class TrainingFabric:
         self._build()
 
     # ------------------------------------------------------------- plumbing
+    def _state_shardings(self):
+        """Per-leaf NamedShardings of the train state on ``self.mesh``.
+
+        ``None`` without a mesh (one default device).  Optimizer m/v follow
+        the parameter shardings; the step counter is replicated.
+        """
+        if self.mesh is None:
+            return None
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        p_sh = param_shardings(
+            self.axes, self.mesh, PARAM_RULES,
+            param_shapes=param_shapes(self.model_cfg),
+        )
+        replicated = NamedSharding(self.mesh, PartitionSpec())
+        return TrainState(params=p_sh,
+                          opt=AdamWState(step=replicated, m=p_sh, v=p_sh))
+
     def _build(self):
-        key = jax.random.PRNGKey(self.train_cfg.seed)
+        state_sh = self._state_shardings()
         if self.state is None:
-            self.state, self.axes = init_state(self.model, key)
+            # created where it lives: each device materializes only its own
+            # shards, so no device ever holds the whole state
+            key = jax.random.PRNGKey(self.train_cfg.seed)
+            self.state = jax.jit(
+                lambda k: init_state(self.model, k)[0], out_shardings=state_sh
+            )(key)
         train_step = make_train_step(self.model, self.train_cfg)
         eval_step = make_eval_step(self.model)
         if self.mesh is not None:
-            from jax.sharding import NamedSharding, PartitionSpec
-
-            shapes = jax.tree_util.tree_map(
-                lambda p: p.shape, self.state.params
-            )
-            shardings = param_shardings(
-                self.axes, self.mesh, PARAM_RULES, param_shapes=shapes
-            )
-            replicated = NamedSharding(self.mesh, PartitionSpec())
-            # optimizer m/v follow param shardings; step is replicated
-            state_sh = TrainState(
-                params=shardings,
-                opt=type(self.state.opt)(
-                    step=replicated, m=shardings, v=shardings
-                ),
-            )
-            self.state = jax.device_put(self.state, state_sh)
-
             def wrapped(state, batch):
                 with use_rules(PARAM_RULES, ACT_RULES, self.mesh):
                     return train_step(state, batch)
@@ -97,13 +104,18 @@ class TrainingFabric:
         else:
             self._train_step = jax.jit(train_step, donate_argnums=0)
         self._eval_step = jax.jit(eval_step)
-        self._data_iter = iter(self.data)
+
+    def _batch(self, step: int) -> dict:
+        """The batch for ``step``, drawn by index, so steps replayed after a
+        restore see the batches the lost steps saw."""
+        host = self.data.batch_at(step)
+        return {k: jnp.asarray(v) for k, v in host.items()}
 
     # ------------------------------------------------------------ functions
     def train_steps(self, n_steps: int = 10, **_) -> dict:
         """Run n training steps; raises NodeFailure at the injected step."""
         t0 = time.time()
-        metrics = {}
+        metrics, losses = {}, []
         for _ in range(n_steps):
             step_now = int(jax.device_get(self.state.step))
             if (
@@ -114,15 +126,16 @@ class TrainingFabric:
                 raise NodeFailure(
                     f"simulated device loss at step {step_now}"
                 )
-            batch = {
-                k: jnp.asarray(v) for k, v in next(self._data_iter).items()
-            }
-            self.state, metrics = self._train_step(self.state, batch)
-        metrics = {k: float(jax.device_get(v)) for k, v in metrics.items()}
+            self.state, metrics = self._train_step(
+                self.state, self._batch(step_now)
+            )
+            losses.append(metrics["loss"])
+        metrics = {k: float(v) for k, v in jax.device_get(metrics).items()}
         record = {
             "step": int(jax.device_get(self.state.step)),
             "seconds": time.time() - t0,
             **metrics,
+            "losses": [float(x) for x in jax.device_get(losses)],
         }
         self.history.append(record)
         return record
@@ -154,25 +167,16 @@ class TrainingFabric:
         return {"checkpoint": path, "step": step}
 
     def restore_latest(self, **_) -> dict:
+        """Replace the state with the latest checkpoint, placed leaf by leaf
+        straight onto this fabric's shardings."""
         self.checkpointer.wait()
-        target = self.state
-        shardings = None
-        if self.mesh is not None:
-            from jax.sharding import NamedSharding, PartitionSpec
-
-            shapes = jax.tree_util.tree_map(
-                lambda p: p.shape, target.params
-            )
-            p_sh = param_shardings(
-                self.axes, self.mesh, PARAM_RULES, param_shapes=shapes
-            )
-            replicated = NamedSharding(self.mesh, PartitionSpec())
-            shardings = TrainState(
-                params=p_sh,
-                opt=type(target.opt)(step=replicated, m=p_sh, v=p_sh),
-            )
+        target = jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), self.state
+        )
+        # the lost state goes first, so the two never share device memory
+        self.state = None
         self.state, meta = ckpt.restore(
-            self.ckpt_dir, target, shardings=shardings
+            self.ckpt_dir, target, shardings=self._state_shardings()
         )
         return {"restored_step": meta["step"]}
 

@@ -1,12 +1,15 @@
 """Pallas kernels vs. pure-jnp oracles: shape/dtype sweeps + properties.
 
-Kernels run in interpret mode on CPU (numerically identical to the compiled
-TPU path)."""
+Kernels run in interpret mode on CPU, asked for explicitly: ``interpret=True``
+on a kernel, or ``pltpu.force_tpu_interpret_mode()`` around a model path that
+reaches the kernels through ``repro.kernels.ops`` (which never interprets on
+its own).  tests/kernels/test_tpu_compile.py compiles them for the chip."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import ops, ref
 from repro.testing import hypothesis_shim
@@ -170,7 +173,8 @@ def test_moe_expert_mlp_matches_ref():
         "up": jax.random.normal(ks[2], (E, D, F)) * 0.1,
         "down": jax.random.normal(ks[3], (E, F, D)) * 0.1,
     }
-    out = ops.moe_expert_mlp(x, experts, cfg)
+    with pltpu.force_tpu_interpret_mode():
+        out = ops.moe_expert_mlp(x, experts, cfg)
     expected = ref.expert_mlp_ref(x, experts)
     np.testing.assert_allclose(out, expected, rtol=2e-4, atol=2e-4)
 
@@ -186,6 +190,36 @@ def test_moe_layer_with_gmm_matches_einsum_path():
     params, _ = materialize(jax.random.PRNGKey(0), spec, jnp.float32)
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 32, cfg.d_model))
     out1, aux1 = moe_mod.apply_moe(params, cfg, x, use_gmm=False)
-    out2, aux2 = moe_mod.apply_moe(params, cfg, x, use_gmm=True)
+    with pltpu.force_tpu_interpret_mode():
+        out2, aux2 = moe_mod.apply_moe(params, cfg, x, use_gmm=True)
     np.testing.assert_allclose(out1, out2, rtol=2e-4, atol=2e-4)
     np.testing.assert_allclose(aux1, aux2, rtol=1e-6, atol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# ops: no hidden interpret fallback, TPU-legal blocks
+# ---------------------------------------------------------------------------
+
+def test_ops_never_interpret_on_their_own():
+    """Off the TPU a kernel call fails unless interpretation was asked for."""
+    x, w = jnp.ones((2, 16, 32)), jnp.ones((2, 32, 16))
+    with pytest.raises(ValueError, match="interpret"):
+        ops.gmm(x, w)
+    with pltpu.force_tpu_interpret_mode():
+        np.testing.assert_allclose(ops.gmm(x, w), ref.gmm_ref(x, w))
+
+
+@pytest.mark.parametrize("n,preferred,expected", [
+    (2048, 128, 128),   # tiles at the preferred block
+    (100, 128, 100),    # shorter than a block: one whole-sequence block
+    (200, 128, 8),      # halves down to one vreg of sublanes
+    (384, 256, 128),
+])
+def test_seq_block_is_tpu_legal(n, preferred, expected):
+    b = ops._seq_block(n, preferred)
+    assert b == expected and n % b == 0 and (b == n or b % 8 == 0)
+
+
+def test_seq_block_refuses_untileable_length():
+    with pytest.raises(ValueError, match="pad it"):
+        ops._seq_block(1030, 128)   # 1030 = 2 * 515: no block of 8..128 rows

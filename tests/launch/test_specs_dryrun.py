@@ -45,7 +45,7 @@ def test_train_cell_lowers_and_compiles(arch):
     with mesh:
         lowered = jax.jit(fn).lower(specs["state"], specs["batch"])
         compiled = lowered.compile()
-    assert hlo_mod.cost_analysis_dict(compiled).get("flops", 0) > 0
+    assert compiled.cost_analysis().get("flops", 0) > 0
     text = compiled.as_text()
     stats = hlo_mod.analyze_collectives(text)
     assert "_total" in stats
@@ -110,6 +110,7 @@ def test_hlo_collective_parser():
 def test_roofline_analyze_math():
     record = {
         "arch": "x", "shape": "train_4k", "mesh": "single", "chips": 256,
+        "device_kind": "TPU v5 lite",
         "kind": "train", "seq_len": 4096, "global_batch": 256,
         "params_total": 2_000_000_000, "params_active": 1_000_000_000,
         "status": "ok",
@@ -124,6 +125,13 @@ def test_roofline_analyze_math():
     # MODEL_FLOPS uses ACTIVE params (MoE correction)
     assert row["model_flops"] == 6.0 * 1e9 * 256 * 4096
     assert 0 < row["roofline_fraction"] <= 1.0
+
+
+def test_roofline_refuses_unknown_device_kind():
+    record = {"arch": "x", "chips": 1, "kind": "decode", "global_batch": 1,
+              "params_active": 1, "device_kind": "cpu"}
+    with pytest.raises(KeyError, match="no published peaks"):
+        analyze(record)
 
 
 def test_model_flops_kinds():

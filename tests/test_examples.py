@@ -31,3 +31,10 @@ def test_publication_flow():
     out = _run("publication_flow.py")
     assert "DOI: 10.18126/repro.000001" in out
     assert "Publication flow complete" in out
+
+
+def test_elastic_training():
+    """Sharded 2x2 training on four virtual devices, killed, resharded."""
+    out = _run("elastic_training.py")
+    assert "resharded: (2, 2) -> (1, 2), restored step 5" in out
+    assert "Elastic training complete" in out
