@@ -24,6 +24,7 @@ EXPERIMENTS.md §Perf.
 from __future__ import annotations
 
 import copy
+import functools
 import secrets
 import threading
 import traceback
@@ -31,6 +32,7 @@ from collections import deque
 from dataclasses import dataclass, field
 from typing import Any, Callable
 
+from .. import obs
 from . import actions as ap
 from . import asl
 from .auth import AuthContext
@@ -68,6 +70,21 @@ RUN_INACTIVE = "INACTIVE"
 #: Long-lived runs (paper: "seconds to weeks") otherwise accumulate events
 #: without bound; beyond the cap the oldest events are dropped and counted.
 MAX_RUN_EVENTS = 256
+
+
+def _run_span(name: str):
+    """Run the decorated ``FlowEngine`` method, whose first argument is a
+    run, inside the span ``name`` tagged with the run's id."""
+
+    def wrap(method):
+        @functools.wraps(method)
+        def spanned(self, run, *args, **kwargs):
+            with obs.span(name, run=run.run_id):
+                return method(self, run, *args, **kwargs)
+
+        return spanned
+
+    return wrap
 
 
 def _error_details(exc: AutomationError) -> dict | None:
@@ -641,27 +658,28 @@ class FlowEngine:
             seq=seq,
             tenant_id=tenant_id,
         )
-        with self._lock:
-            self.runs[run.run_id] = run
-            self.stats["runs_started"] += 1
-        self.journal.append(
-            {
-                "type": "run_created",
-                "run_id": run.run_id,
-                "flow_id": flow_id,
-                "input": run.context,
-                "creator": creator,
-                "label": label,
-                "seq": seq,
-                "t": run.start_time,
-                **({"tenant": tenant_id} if tenant_id is not None else {}),
-            }
-        )
-        run.log_event(run.start_time, "FlowStarted", input=flow_input)
-        if defer_start:
-            run.deferred = True
-        else:
-            self.scheduler.submit(lambda: self._enter_state(run, flow.start_at))
+        with obs.span("flows.start", run=run.run_id):
+            with self._lock:
+                self.runs[run.run_id] = run
+                self.stats["runs_started"] += 1
+            self.journal.append(
+                {
+                    "type": "run_created",
+                    "run_id": run.run_id,
+                    "flow_id": flow_id,
+                    "input": run.context,
+                    "creator": creator,
+                    "label": label,
+                    "seq": seq,
+                    "t": run.start_time,
+                    **({"tenant": tenant_id} if tenant_id is not None else {}),
+                }
+            )
+            run.log_event(run.start_time, "FlowStarted", input=flow_input)
+            if defer_start:
+                run.deferred = True
+            else:
+                self.scheduler.submit(lambda: self._enter_state(run, flow.start_at))
         return run
 
     def release_run(self, run: Run) -> None:
@@ -862,6 +880,7 @@ class FlowEngine:
         return offset
 
     # ----------------------------------------------------------- state machine
+    @_run_span("flows.enter")
     def _enter_state(self, run: Run, state_name: str, attempt: int = 0) -> None:
         with run.lock:
             if run.status != RUN_ACTIVE:
@@ -1259,13 +1278,14 @@ class FlowEngine:
             }
         )
         try:
-            status = provider.run(
-                body,
-                caller=caller,
-                request_id=request_id,
-                monitor_by=sorted(run.monitor_by),
-                manage_by=sorted(run.manage_by),
-            )
+            with obs.span("flows.dispatch", run=run.run_id, request=request_id):
+                status = provider.run(
+                    body,
+                    caller=caller,
+                    request_id=request_id,
+                    monitor_by=sorted(run.monitor_by),
+                    manage_by=sorted(run.manage_by),
+                )
         except AutomationError as e:
             self._state_failed(run, state, e.error_name, e.cause, _error_details(e))
             return
@@ -1382,6 +1402,7 @@ class FlowEngine:
             return
         self._action_finished(run, state, status)
 
+    @_run_span("flows.finish")
     def _action_finished(self, run: Run, state: asl.State, status) -> None:
         with run.lock:
             if run.status != RUN_ACTIVE:
@@ -1933,6 +1954,7 @@ class FlowEngine:
     def _goto(self, run: Run, state_name: str) -> None:
         self.scheduler.submit(lambda: self._enter_state(run, state_name))
 
+    @_run_span("flows.complete")
     def _complete_run(self, run: Run, status: str) -> None:
         with run.lock:
             if run.status != RUN_ACTIVE:

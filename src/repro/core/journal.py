@@ -40,6 +40,7 @@ import threading
 import time
 from typing import Any, Callable, Iterator
 
+from .. import obs
 from . import jsonpath
 
 
@@ -344,20 +345,21 @@ class Journal:
         """
         if self.fenced is not None:
             raise JournalFenced(self.fenced)
-        line = json.dumps(record, separators=(",", ":"), default=_jsonable)
-        try:
-            if self.group_commit:
-                self._committer.append_and_commit(line)
-            else:
-                # serialized baseline: one durability round trip per record,
-                # taken while holding the journal lock
-                with self._lock:
-                    self._flush_batch([line])
-        finally:
-            # the leader that flushed our batch parked our offset under this
-            # exact string object's id; claim it (pop even on failure so the
-            # handoff dict cannot leak entries for poisoned appends)
-            offset = self._offsets.pop(id(line), None)
+        with obs.span("journal.append", run=record.get("run_id", "")):
+            line = json.dumps(record, separators=(",", ":"), default=_jsonable)
+            try:
+                if self.group_commit:
+                    self._committer.append_and_commit(line)
+                else:
+                    # serialized baseline: one durability round trip per
+                    # record, taken while holding the journal lock
+                    with self._lock:
+                        self._flush_batch([line])
+            finally:
+                # the leader that flushed our batch parked our offset under this
+                # exact string object's id; claim it (pop even on failure so the
+                # handoff dict cannot leak entries for poisoned appends)
+                offset = self._offsets.pop(id(line), None)
         if (
             self.compact_every is not None
             and self._since_checkpoint > self.compact_every

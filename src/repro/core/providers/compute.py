@@ -21,9 +21,11 @@ from __future__ import annotations
 
 import secrets
 import threading
+import time
 from dataclasses import dataclass
 from typing import Any, Callable
 
+from ... import obs
 from ..actions import FAILED, SUCCEEDED, ActionProvider, _Action
 from ..auth import Identity
 from ..errors import NodeFailure, NotFound
@@ -136,20 +138,26 @@ class ComputeProvider(ActionProvider):
         else:
             self._run_inline(action, endpoint, tasks)
 
-    def _execute(self, tasks: list[dict]) -> tuple[list[Any], float]:
+    def _execute(
+        self, action: _Action, tasks: list[dict], queued_ms: float = 0.0
+    ) -> tuple[list[Any], float]:
+        """Run the tasks; ``queued_ms`` is how long the action waited for
+        the endpoint's worker."""
         results = []
         modeled = 0.0
-        for t in tasks:
-            f = self._function(t["function_id"])
-            kwargs = t.get("kwargs", {})
-            if f.modeled_duration is not None:
-                modeled += float(f.modeled_duration(kwargs))
-            results.append(f.fn(**kwargs))
+        with obs.span("compute.run", request=action.request_id or "",
+                      queued_ms=queued_ms):
+            for t in tasks:
+                f = self._function(t["function_id"])
+                kwargs = t.get("kwargs", {})
+                if f.modeled_duration is not None:
+                    modeled += float(f.modeled_duration(kwargs))
+                results.append(f.fn(**kwargs))
         return results, modeled
 
     def _run_inline(self, action: _Action, endpoint, tasks: list[dict]) -> None:
         try:
-            results, modeled = self._execute(tasks)
+            results, modeled = self._execute(action, tasks)
         except NodeFailure as e:
             self._complete(
                 action, FAILED, details={"error": str(e), "error_type": "NodeFailure"}
@@ -171,6 +179,7 @@ class ComputeProvider(ActionProvider):
     def _run_threaded(self, action: _Action, endpoint, tasks: list[dict]) -> None:
         from concurrent.futures import ThreadPoolExecutor
 
+        queued_at = time.perf_counter()
         with self._reg_lock:
             pool = self._pools.get(endpoint.endpoint_id)
             if pool is None:
@@ -181,8 +190,9 @@ class ComputeProvider(ActionProvider):
         action.display_status = f"queued on {endpoint.name}"
 
         def work():
+            queued_ms = (time.perf_counter() - queued_at) * 1e3
             try:
-                results, _ = self._execute(tasks)
+                results, _ = self._execute(action, tasks, queued_ms)
             except NodeFailure as e:
                 self._complete(
                     action,

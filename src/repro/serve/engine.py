@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.models.model import Model
 
 
@@ -34,9 +35,11 @@ class ServeEngine:
         self.key = jax.random.PRNGKey(seed)
         self.stats = {"requests": 0, "batches": 0, "tokens_generated": 0,
                       "prefill_tokens": 0}
-        self._jit_prefill = jax.jit(
-            lambda p, b: model.prefill(p, b, self.max_len)
-        )
+
+        def prefill(params, batch):
+            return model.prefill(params, batch, self.max_len)
+
+        self._jit_prefill = jax.jit(prefill)
         self._jit_decode = jax.jit(model.decode_step)
 
     # ------------------------------------------------------------------
@@ -64,19 +67,21 @@ class ServeEngine:
         self.stats["requests"] += B
         self.stats["batches"] += 1
         self.stats["prefill_tokens"] += int(B * S)
-        tokens = jnp.asarray(prompts, jnp.int32)
-        batch = {"tokens": tokens}
-        if frames is not None:
-            batch["frames"] = jnp.asarray(frames)
-        if pixel_embeds is not None:
-            batch["pixel_embeds"] = jnp.asarray(pixel_embeds)
-        logits, cache = self._jit_prefill(self.params, batch)
+        # the prefill's span runs until its token is on the host
+        with obs.span("serve.prefill", rows=B, length=S):
+            tokens = jnp.asarray(prompts, jnp.int32)
+            batch = {"tokens": tokens}
+            if frames is not None:
+                batch["frames"] = jnp.asarray(frames)
+            if pixel_embeds is not None:
+                batch["pixel_embeds"] = jnp.asarray(pixel_embeds)
+            logits, cache = self._jit_prefill(self.params, batch)
+            cur = np.asarray(self._sample(logits))
         kept_logits = [logits]
         position = S
 
         out = []
         done = np.zeros(B, bool)
-        cur = np.asarray(self._sample(logits))
         for step in range(max_new_tokens):
             out.append(np.where(done, self.eos or 0, cur))
             if self.eos is not None:
@@ -85,14 +90,18 @@ class ServeEngine:
                     break
             if step == max_new_tokens - 1:
                 break
-            logits, cache = self._jit_decode(
-                self.params, jnp.asarray(cur[:, None], jnp.int32), cache,
-                jnp.asarray(position, jnp.int32),
-            )
+            # one decode step: upload the token and position, dispatch the
+            # step, then pull its token, where the host waits on the device
+            with obs.span("serve.decode"):
+                logits, cache = self._jit_decode(
+                    self.params, jnp.asarray(cur[:, None], jnp.int32), cache,
+                    jnp.asarray(position, jnp.int32),
+                )
             position += 1
             if step == 0:
                 kept_logits.append(logits)
-            cur = np.asarray(self._sample(logits))
+            with obs.span("serve.pull"):
+                cur = np.asarray(self._sample(logits))
         generated = np.stack(out, axis=1) if out else np.zeros((B, 0), np.int32)
         self.stats["tokens_generated"] += int(generated.size)
         return {"tokens": generated, "prompt_len": S, "logits": kept_logits}

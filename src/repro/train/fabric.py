@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.configs.base import ModelConfig, TrainConfig
 from repro.core.errors import NodeFailure
 from repro.models.model import Model, param_axes, param_shapes
@@ -93,16 +94,16 @@ class TrainingFabric:
             self.state = jax.jit(
                 lambda k: init_state(self.model, k)[0], out_shardings=state_sh
             )(key)
-        train_step = make_train_step(self.model, self.train_cfg)
+        step_fn = make_train_step(self.model, self.train_cfg)
         eval_step = make_eval_step(self.model)
         if self.mesh is not None:
-            def wrapped(state, batch):
+            def train_step(state, batch):
                 with use_rules(PARAM_RULES, ACT_RULES, self.mesh):
-                    return train_step(state, batch)
+                    return step_fn(state, batch)
 
-            self._train_step = jax.jit(wrapped, donate_argnums=0)
-        else:
             self._train_step = jax.jit(train_step, donate_argnums=0)
+        else:
+            self._train_step = jax.jit(step_fn, donate_argnums=0)
         self._eval_step = jax.jit(eval_step)
 
     def _batch(self, step: int) -> dict:
@@ -117,7 +118,9 @@ class TrainingFabric:
         t0 = time.time()
         metrics, losses = {}, []
         for _ in range(n_steps):
-            step_now = int(jax.device_get(self.state.step))
+            # the host waits here for the previous step to finish
+            with obs.span("train.sync"):
+                step_now = int(jax.device_get(self.state.step))
             if (
                 self.inject_failure_at is not None
                 and step_now >= self.inject_failure_at
@@ -126,9 +129,10 @@ class TrainingFabric:
                 raise NodeFailure(
                     f"simulated device loss at step {step_now}"
                 )
-            self.state, metrics = self._train_step(
-                self.state, self._batch(step_now)
-            )
+            with obs.span("train.batch", step=step_now):
+                batch = self._batch(step_now)
+            with obs.span("train.dispatch", step=step_now):
+                self.state, metrics = self._train_step(self.state, batch)
             losses.append(metrics["loss"])
         metrics = {k: float(v) for k, v in jax.device_get(metrics).items()}
         record = {
