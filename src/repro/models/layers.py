@@ -121,6 +121,25 @@ def apply_unembed(params, x, logit_softcap: float | None = None):
     return logits
 
 
+def cast_at_use(path: tuple[str, ...]) -> bool:
+    """Whether every program casts the parameter leaf at ``path`` (its dict
+    keys from the root) to the compute dtype before it reads it.
+
+    So are a dense layer's ``w`` and ``b`` (``apply_dense``, whose
+    activations are always in the compute dtype), an embedding ``table``
+    (``apply_embedding``, ``apply_unembed``) and the MoE expert stacks
+    (``moe.apply_moe``).  The MoE router's ``w`` is read in float32, as are
+    norm scales and the SSM decay terms: those are not.  The serving view
+    (``Model.serving_params``) casts such leaves once, which gives the very
+    values these casts make at every use.
+    """
+    if "router" in path:
+        return False
+    if len(path) > 1 and path[-2] == "experts":
+        return True
+    return path[-1] in ("w", "b", "table")
+
+
 # ---------------------------------------------------------------------------
 # RoPE
 # ---------------------------------------------------------------------------

@@ -6,6 +6,7 @@
     loss         = model.loss(params, batch)
     logits, cache = model.prefill(params, batch, max_len)
     logits, cache = model.decode_step(params, tokens, cache, position)
+    weights      = model.serving_params(params)  # what prefill/decode read
 
 ``batch`` keys by family:
     lm / moe / ssm / hybrid : tokens [B,S], labels [B,S]
@@ -14,6 +15,8 @@
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -76,6 +79,11 @@ def active_params_analytic(cfg: ModelConfig) -> int:
     return total - expert_total + expert_active
 
 
+@functools.partial(jax.jit, static_argnums=1)
+def _cast_leaves(leaves, dtype):
+    return [leaf.astype(dtype) for leaf in leaves]
+
+
 class Model:
     def __init__(self, cfg: ModelConfig):
         self.cfg = cfg
@@ -112,6 +120,27 @@ class Model:
         return ce
 
     # --------------------------------------------------------------- serving
+    def serving_params(self, params):
+        """The weights as the serving programs read them: each leaf that
+        every program casts to ``compute_dtype`` before use
+        (``layers.cast_at_use``) cast once, in one jitted call; every other
+        leaf is the very array of ``params``.  ``prefill`` and
+        ``decode_step`` give bitwise the same results from this tree as
+        from ``params``.  Training differentiates through ``params`` and
+        keeps its casts."""
+        dtype = jnp.dtype(self.cfg.compute_dtype)
+        flat, treedef = jax.tree_util.tree_flatten_with_path(params)
+        leaves = [leaf for _, leaf in flat]
+        cast = [i for i, (path, leaf) in enumerate(flat)
+                if leaf.dtype != dtype
+                and L.cast_at_use(tuple(getattr(k, "key", None)
+                                        for k in path))]
+        if cast:
+            new = _cast_leaves([leaves[i] for i in cast], dtype)
+            for i, leaf in zip(cast, new):
+                leaves[i] = leaf
+        return treedef.unflatten(leaves)
+
     def prefill(self, params, batch, max_len: int):
         cfg = self.cfg
         if cfg.family == "encdec":

@@ -4,7 +4,9 @@ Requests are grouped into generation batches (arrival-window batching);
 each batch is prefim-filled once and decoded in lockstep, with per-row EOS
 masking.  Attention families use prefill+KV cache; recurrent families
 (xlstm / zamba2) consume the prompt through their O(1)-state decode path.
-The jitted step functions are cached per (batch, prompt_len) bucket.
+The jitted step functions are cached per (batch, prompt_len) bucket, and
+read a copy of the weights cast to the compute dtype once per weight
+assignment (``Model.serving_params``), not at every step.
 """
 
 from __future__ import annotations
@@ -28,19 +30,48 @@ class ServeEngine:
         seed: int = 0,
     ):
         self.model = model
-        self.params = params
         self.max_len = max_len
         self.eos = eos_token
         self.greedy = greedy
         self.key = jax.random.PRNGKey(seed)
         self.stats = {"requests": 0, "batches": 0, "tokens_generated": 0,
-                      "prefill_tokens": 0}
+                      "prefill_tokens": 0, "weight_views": 0,
+                      "weight_view_bytes": 0}
+        self.params = params
 
+        # the programs hold no reference to the engine, so dropping the
+        # engine frees its serving weights at once
         def prefill(params, batch):
-            return model.prefill(params, batch, self.max_len)
+            return model.prefill(params, batch, max_len)
 
         self._jit_prefill = jax.jit(prefill)
         self._jit_decode = jax.jit(model.decode_step)
+
+    @property
+    def params(self):
+        """The weights as assigned, in the model's ``param_dtype``.
+
+        Assigning them builds, once, the view the programs read
+        (``serving_params``, from ``Model.serving_params``):
+        ``stats["weight_views"]`` counts the views built and
+        ``stats["weight_view_bytes"]`` holds the device bytes that the
+        current view adds beside ``params``.
+        """
+        return self._params
+
+    @params.setter
+    def params(self, params):
+        self._params = params
+        self.serving_params = None  # the old view goes before the new
+        with obs.span("serve.weights"):
+            self.serving_params = jax.block_until_ready(
+                self.model.serving_params(params))
+        self.stats["weight_views"] += 1
+        self.stats["weight_view_bytes"] = sum(
+            int(view.nbytes) for view, leaf in zip(
+                jax.tree_util.tree_leaves(self.serving_params),
+                jax.tree_util.tree_leaves(params))
+            if view is not leaf)
 
     # ------------------------------------------------------------------
     def _sample(self, logits: jnp.ndarray) -> jnp.ndarray:
@@ -75,7 +106,7 @@ class ServeEngine:
                 batch["frames"] = jnp.asarray(frames)
             if pixel_embeds is not None:
                 batch["pixel_embeds"] = jnp.asarray(pixel_embeds)
-            logits, cache = self._jit_prefill(self.params, batch)
+            logits, cache = self._jit_prefill(self.serving_params, batch)
             cur = np.asarray(self._sample(logits))
         kept_logits = [logits]
         position = S
@@ -94,8 +125,8 @@ class ServeEngine:
             # step, then pull its token, where the host waits on the device
             with obs.span("serve.decode"):
                 logits, cache = self._jit_decode(
-                    self.params, jnp.asarray(cur[:, None], jnp.int32), cache,
-                    jnp.asarray(position, jnp.int32),
+                    self.serving_params, jnp.asarray(cur[:, None], jnp.int32),
+                    cache, jnp.asarray(position, jnp.int32),
                 )
             position += 1
             if step == 0:
