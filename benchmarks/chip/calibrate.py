@@ -43,7 +43,8 @@ def control_readings(cell, seed: int, run) -> dict:
                                      mix["batch"], mix["seq_len"])
         ctl = cell.reference.train_readings(
             cell.model, mix["optimizer"], seed, data, mix["check"]["steps"],
-            mix["check"]["rows_per_block"], control=True)
+            mix["check"]["rows_per_block"], control=True,
+            mesh=training.mesh_of(cell))
         return training.compare(ctl, run.data["want"])
     from benchmarks.chip import serving
 

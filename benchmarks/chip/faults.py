@@ -14,7 +14,8 @@ import numpy as np
 
 from benchmarks.chip.references import dense_lm
 
-FAULTS = ("unchanged", "half_batch", "altered_token", "control")
+FAULTS = ("unchanged", "half_batch", "no_exchange", "altered_token",
+          "control")
 
 
 def plant(fault: str, monkeypatch) -> None:
@@ -24,6 +25,10 @@ def plant(fault: str, monkeypatch) -> None:
     * ``unchanged``: every train step returns its state unchanged;
     * ``half_batch``: every train step sees the first half of its batch
       only, and takes the mean over it;
+    * ``no_exchange`` (a fabric with a mesh): no gradient crosses between
+      the data-parallel groups: each takes the gradient of its own rows
+      for the parameter shards it holds (the first group's for those it
+      shares) and reports its own loss;
     * ``altered_token``: the last token each served batch generates is
       moved to the next id of the vocabulary;
     * ``control``: the plain reference with every matmul in fp8
@@ -41,7 +46,7 @@ def plant(fault: str, monkeypatch) -> None:
 
         def patched(self):
             build(self)
-            step = make_train_step(self.model, self.train_cfg)
+            step = _on_mesh(self, make_train_step(self.model, self.train_cfg))
             if fault == "unchanged":
                 self._train_step = jax.jit(lambda s, b: (s, step(s, b)[1]))
             else:
@@ -49,6 +54,17 @@ def plant(fault: str, monkeypatch) -> None:
                     lambda s, b: step(s, {k: v[: v.shape[0] // 2]
                                           for k, v in b.items()}),
                     donate_argnums=0)
+
+        monkeypatch.setattr(TrainingFabric, "_build", patched)
+    elif fault == "no_exchange":
+        from repro.train.fabric import TrainingFabric
+
+        build = TrainingFabric._build
+
+        def patched(self):
+            build(self)
+            self._train_step = jax.jit(_on_mesh(self, _no_exchange(self)),
+                                       donate_argnums=0)
 
         monkeypatch.setattr(TrainingFabric, "_build", patched)
     elif fault == "altered_token":
@@ -65,6 +81,56 @@ def plant(fault: str, monkeypatch) -> None:
         monkeypatch.setattr(ServeEngine, "generate", altered)
     else:
         raise KeyError(f"no fault {fault!r}; known: {FAULTS}")
+
+
+def _on_mesh(fabric, step):
+    """``step`` under the fabric's sharding rules, as its own step runs,
+    where the fabric has a mesh."""
+    if fabric.mesh is None:
+        return step
+    from repro.parallel.sharding import ACT_RULES, PARAM_RULES, use_rules
+
+    def on_mesh(state, batch):
+        with use_rules(PARAM_RULES, ACT_RULES, fabric.mesh):
+            return step(state, batch)
+
+    return on_mesh
+
+
+def _no_exchange(fabric):
+    """A train step of ``fabric`` whose data-parallel groups share no
+    gradient (``no_exchange``)."""
+    from repro.train import optimizer
+    from repro.train.loop import TrainState
+
+    n = dict(zip(fabric.mesh.axis_names, fabric.mesh.devices.shape))["data"]
+    specs = jax.tree_util.tree_map(lambda x: x.sharding.spec,
+                                   fabric.state.params)
+
+    def own(spec, *grads):
+        """Each group's gradient on the slice of the leaf that it holds."""
+        g = grads[0]
+        dims = [d for d, part in enumerate(spec)
+                if "data" in (part if isinstance(part, tuple) else (part,))]
+        if not dims:
+            return g
+        group = jax.lax.broadcasted_iota(jnp.int32, g.shape, dims[0]) \
+            // (g.shape[dims[0]] // n)
+        return jnp.select([group == k for k in range(n)], list(grads))
+
+    def step(state, batch):
+        rows = batch["tokens"].shape[0] // n
+        losses, grads = zip(*[jax.value_and_grad(fabric.model.loss)(
+            state.params, {k: v[i * rows:(i + 1) * rows]
+                           for k, v in batch.items()}) for i in range(n)])
+        mixed = jax.tree_util.tree_map(own, specs, *grads,
+                                       is_leaf=lambda x: isinstance(
+                                           x, jax.sharding.PartitionSpec))
+        params, opt_state, metrics = optimizer.adamw_update(
+            state.params, mixed, state.opt, fabric.train_cfg)
+        return TrainState(params, opt_state), {"loss": losses[0], **metrics}
+
+    return step
 
 
 def _model_cfg(model) -> dict:

@@ -102,14 +102,42 @@ def _init(key, shapes_items: tuple, dtype: str):
     return out
 
 
-def init_params(cfg: dict, seed: int, dtype: str | None = None):
+def init_params(cfg: dict, seed: int, dtype: str | None = None,
+                shardings=None):
     """The weights of seed ``seed``, made on the device in one jitted call,
-    in the configuration's parameter dtype."""
+    in the configuration's parameter dtype.  With ``shardings`` (a tree of
+    the parameters' shape, one sharding a leaf) each device makes only its
+    own shards; the numbers are the same."""
     shapes = param_shapes(cfg)
     leaves, treedef = jax.tree_util.tree_flatten(shapes, is_leaf=_is_leaf)
     items = tuple((tuple(s), f) for s, f in leaves)
-    made = _init(root_key(seed), items, dtype or cfg["param_dtype"])
+    init = _init if shardings is None else jax.jit(
+        _init.__wrapped__, static_argnums=(1, 2),
+        out_shardings=treedef.flatten_up_to(shardings))
+    made = init(root_key(seed), items, dtype or cfg["param_dtype"])
     return jax.tree_util.tree_unflatten(treedef, made)
+
+
+def spread(cfg: dict, mesh) -> dict:
+    """A sharding of each parameter over every device of ``mesh``: split
+    along its largest axis that the device count divides (the first of
+    equals), whole where none does.  Placement only; XLA partitions the
+    same arithmetic over it."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    n = mesh.devices.size
+
+    def leaf(spec):
+        shape = spec[0]
+        fits = [i for i, d in enumerate(shape) if d % n == 0]
+        if not fits:
+            return NamedSharding(mesh, PartitionSpec())
+        axis = max(fits, key=lambda i: (shape[i], -i))
+        parts = [None] * len(shape)
+        parts[axis] = tuple(mesh.axis_names)
+        return NamedSharding(mesh, PartitionSpec(*parts))
+
+    return jax.tree_util.tree_map(leaf, param_shapes(cfg), is_leaf=_is_leaf)
 
 
 # ------------------------------------------------------------------ forward
@@ -249,16 +277,20 @@ _norms = jax.jit(lambda tree: [jnp.linalg.norm(x)
 
 
 def train_readings(cfg: dict, opt: dict, seed: int, data, steps: int,
-                   rows_per_block: int, control: bool = False) -> dict:
+                   rows_per_block: int, control: bool = False,
+                   mesh=None) -> dict:
     """The reference's readings over the first ``steps`` steps from the
     seed's weights on ``data.batch_at(0 ..)``: each step's loss, the
     per-leaf norm of the first clipped gradient, and the per-leaf norm of
     each parameter's change after the last step.  Rows go through in
     blocks; Adam's moments wait on the host while a gradient is computed,
     so the device never holds more than weights, two gradients and one
-    block's activations."""
+    block's activations.  With ``mesh`` every parameter, gradient and
+    moment is spread over its devices (:func:`spread`), so each holds a
+    share of them, and the moments stay there."""
     cfg_items = items(cfg)
-    params = init_params(cfg, seed)
+    shardings = None if mesh is None else spread(cfg, mesh)
+    params = init_params(cfg, seed, shardings=shardings)
     m = v = None
     losses, first_grad = [], None
     for step in range(steps):
@@ -282,14 +314,15 @@ def train_readings(cfg: dict, opt: dict, seed: int, data, steps: int,
             first_grad = [x * scale for x in leaf_norms]
             m = jax.tree_util.tree_map(jnp.zeros_like, params)
             v = jax.tree_util.tree_map(jnp.zeros_like, params)
-        else:
+        elif shardings is None:
             m, v = jax.device_put((m, v))
         params, m, v = _adamw(params, grads, m, v, scale, lr_at(opt, step),
                               float(step + 1), opt["beta1"], opt["beta2"],
                               opt["eps"], opt["weight_decay"])
         grads = None
-        m, v = jax.device_get((m, v)) if step + 1 < steps else (None, None)
-    p0 = init_params(cfg, seed)
+        if shardings is None:
+            m, v = jax.device_get((m, v)) if step + 1 < steps else (None, None)
+    p0 = init_params(cfg, seed, shardings=shardings)
     change = [float(x) for x in jax.device_get(_norms(
         jax.tree_util.tree_map(jnp.subtract, params, p0)))]
     del p0, params

@@ -10,7 +10,9 @@ step 1 (Adam's first moment over ``1 - beta1``), and each parameter's
 change after step 3.  The same fabric then trains through the window,
 segment by segment, until the first segment that ends after ``seconds``.
 After the window, with the program's state freed, the plain reference
-runs the same three steps in float32.
+runs the same three steps in float32.  A mix that names a mesh has the
+fabric train over it, with the seed's state and the reference spread over
+the cell's chips.
 """
 
 from __future__ import annotations
@@ -47,6 +49,21 @@ def flow_definition(chip_dir, name: str, ids: dict, steps: int) -> dict:
     return json.loads(text)
 
 
+def mesh_of(cell):
+    """The mesh the traffic mix names (``"mesh": {"shape": [2, 2], "axes":
+    ["data", "model"]}``) over the cell's chips, or ``None`` where it names
+    none: the fabric then trains on one device."""
+    spec = cell.traffic.get("mesh")
+    if spec is None:
+        return None
+    from repro.launch.mesh import make_mesh
+
+    if math.prod(spec["shape"]) != cell.chips:
+        raise ValueError(f"mesh {spec['shape']} does not cover the cell's "
+                         f"{cell.chips} chips")
+    return make_mesh(tuple(spec["shape"]), tuple(spec["axes"]))
+
+
 def run(cell, seed: int, seconds: float, trace: bool, t_process: float
         ) -> RunResult:
     import jax
@@ -63,15 +80,28 @@ def run(cell, seed: int, seconds: float, trace: bool, t_process: float
     opt = mix["optimizer"]
     workdir = cell.root / ".bench_work" / cell.name
     data = SeededTokens(seed, cfg["vocab_size"], batch, seq)
+    mesh = mesh_of(cell)
     fabric = TrainingFabric(ModelConfig(**cfg), TrainConfig(**opt),
                             batch=batch, seq_len=seq,
-                            ckpt_dir=str(workdir / "ckpt"), data=data)
-    # the benchmark's weights from the seed, in place of the fabric's own
+                            ckpt_dir=str(workdir / "ckpt"), mesh=mesh,
+                            data=data)
+    # the benchmark's weights from the seed, in place of the fabric's own,
+    # on the fabric's own shardings where it has a mesh: no device ever
+    # holds the whole state
+    where = None if mesh is None else jax.tree_util.tree_map(
+        lambda x: x.sharding, fabric.state)
     free(fabric.state)
-    params = ref.init_params(cfg, seed)
-    zeros = jax.jit(lambda p: jax.tree_util.tree_map(jnp.zeros_like, p))
+    p_where = None if where is None else where.params
+    params = ref.init_params(cfg, seed, shardings=p_where)
+    if where is None:
+        zeros = jax.jit(lambda p: jax.tree_util.tree_map(jnp.zeros_like, p))
+        step0 = jnp.zeros((), jnp.int32)
+    else:
+        zeros = jax.jit(lambda p: jax.tree_util.tree_map(jnp.zeros_like, p),
+                        out_shardings=where.opt.m)
+        step0 = jax.device_put(jnp.zeros((), jnp.int32), where.opt.step)
     fabric.state = TrainState(params=params, opt=AdamWState(
-        step=jnp.zeros((), jnp.int32), m=zeros(params), v=zeros(params)))
+        step=step0, m=zeros(params), v=zeros(params)))
     params = None
 
     flows, compute = build_stack(str(workdir))
@@ -123,7 +153,7 @@ def run(cell, seed: int, seconds: float, trace: bool, t_process: float
                       for x in jax.device_get(norms(fabric.state.opt.m))]
         stop["at"] = None
         run_flow(mix["check"]["steps"] - 1, "steps-2-3")
-        p0 = ref.init_params(cfg, seed)
+        p0 = ref.init_params(cfg, seed, shardings=p_where)
         change = jax.device_get(norms(jax.tree_util.tree_map(
             lambda a, b: a - b, fabric.state.params, p0)))
         free(p0)
@@ -151,7 +181,7 @@ def run(cell, seed: int, seconds: float, trace: bool, t_process: float
 
     t_ref = time.time()
     want = ref.train_readings(cfg, opt, seed, data, mix["check"]["steps"],
-                              mix["check"]["rows_per_block"])
+                              mix["check"]["rows_per_block"], mesh=mesh)
     log(f"reference: {mix['check']['steps']} steps in "
         f"{time.time() - t_ref:.3f} s")
     got = {"losses": checked_losses, "first_grad": first_grad,
@@ -166,7 +196,8 @@ def run(cell, seed: int, seconds: float, trace: bool, t_process: float
         checks=checks, memory_peak_bytes=peak, trace=summary,
         device_kind=jax.devices()[0].device_kind,
         data={"segments": window, "tokens_per_step": batch * seq,
-              "seq_len": seq, "chips": 1, "got": got, "want": want},
+              "seq_len": seq, "chips": cell.chips, "got": got,
+              "want": want},
     )
 
 

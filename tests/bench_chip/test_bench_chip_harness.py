@@ -4,6 +4,7 @@ refuses to print a result off the chip."""
 from __future__ import annotations
 
 import json
+import math
 import os
 import shutil
 import subprocess
@@ -117,6 +118,11 @@ def test_every_committed_cell_resolves_to_files_that_exist():
             assert (cell.chip_dir / "metrics" / f"{name}.py").exists(), name
         assert "setup_s" in cell.end_to_end and len(cell.end_to_end) >= 2
         assert cell.per_layer
+        # a mix that names a mesh covers the cell's chips with named axes
+        mesh = cell.traffic.get("mesh")
+        if mesh is not None:
+            assert math.prod(mesh["shape"]) == cell.chips, w["name"]
+            assert len(mesh["axes"]) == len(mesh["shape"]), w["name"]
 
 
 def _run_py(cwd: Path, *args) -> subprocess.CompletedProcess:
