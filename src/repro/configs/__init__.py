@@ -4,6 +4,7 @@ from .base import (
     ARCH_IDS,
     LONG_CONTEXT_ARCHS,
     SHAPES,
+    MLAConfig,
     ModelConfig,
     MoEConfig,
     ShapeConfig,
@@ -19,6 +20,7 @@ __all__ = [
     "ARCH_IDS",
     "LONG_CONTEXT_ARCHS",
     "SHAPES",
+    "MLAConfig",
     "ModelConfig",
     "MoEConfig",
     "ShapeConfig",

@@ -14,12 +14,40 @@ from dataclasses import dataclass
 
 @dataclass(frozen=True)
 class MoEConfig:
-    n_experts: int = 8
+    """Expert layer.  The GQA stack (mixtral, qwen3) runs the capacity
+    layer; the latent-attention stack (``mla`` set) runs the dropless
+    expert-parallel layer, which reads the fields after ``aux_loss_weight``.
+    """
+
+    n_experts: int = 8       # experts held here (all of them, unless shared out)
     top_k: int = 2
     d_ff: int = 0  # per-expert hidden size
     capacity_factor: float = 1.25
     router_jitter: float = 0.0
     aux_loss_weight: float = 0.01
+    # dropless expert-parallel layer
+    router_experts: int = 0  # experts the router scores; 0: n_experts
+    first_expert: int = 0    # router index of the first expert held here
+    shared_d_ff: int = 0     # shared experts as one SwiGLU of this width; 0: none
+    score_func: str = "softmax"   # softmax | sigmoid
+    norm_topk: bool = True   # the top-k weights renormalised to sum to 1
+    routed_scale: float = 1.0     # the routed experts' output scale
+
+    @property
+    def n_routed(self) -> int:
+        return self.router_experts or self.n_experts
+
+
+@dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2): a low-rank latent of
+    keys and values, cached in place of them, beside one rotary key head
+    shared by every query head."""
+
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
 
 
 @dataclass(frozen=True)
@@ -77,6 +105,8 @@ class ModelConfig:
     tie_embeddings: bool = False
     # sub-configs
     moe: MoEConfig | None = None
+    mla: MLAConfig | None = None
+    n_dense_layers: int = 0  # leading d_ff-wide SwiGLU layers (latent stack)
     ssm: SSMConfig | None = None
     xlstm: XLSTMConfig | None = None
     zamba: ZambaConfig | None = None
@@ -97,6 +127,13 @@ class ModelConfig:
     # per-layer FLOPs/bytes; training keeps scan for compact HLO
     unroll_layers: bool = False
 
+    def __post_init__(self):
+        # a configuration read from JSON gives its sub-sections as dicts
+        for name, kind in _SUBCONFIGS.items():
+            value = getattr(self, name)
+            if isinstance(value, dict):
+                object.__setattr__(self, name, kind(**value))
+
     @property
     def resolved_head_dim(self) -> int:
         return self.head_dim or self.d_model // self.n_heads
@@ -109,6 +146,10 @@ class ModelConfig:
         from repro.models.model import count_params_analytic
 
         return count_params_analytic(self)
+
+
+_SUBCONFIGS = {"moe": MoEConfig, "mla": MLAConfig, "ssm": SSMConfig,
+               "xlstm": XLSTMConfig, "zamba": ZambaConfig}
 
 
 @dataclass(frozen=True)
@@ -166,6 +207,7 @@ ARCH_IDS = [
     "zamba2-7b",
     "whisper-medium",
     "internvl2-2b",
+    "moonlight-16b-a3b",
 ]
 
 _MODULES = {
@@ -179,6 +221,7 @@ _MODULES = {
     "zamba2-7b": "zamba2_7b",
     "whisper-medium": "whisper_medium",
     "internvl2-2b": "internvl2_2b",
+    "moonlight-16b-a3b": "moonlight_16b_a3b",
 }
 
 

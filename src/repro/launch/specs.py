@@ -182,6 +182,16 @@ def cache_axes(cfg: ModelConfig) -> object:
             else None,
         }
         return out
+    if cfg.mla is not None:
+        from repro.models.transformer import latent_stacks
+
+        latent = {"ckv": ("batch", "kv_seq", None),
+                  "kpe": ("batch", "kv_seq", None)}
+        out = {name: _stackN(latent, "layers")
+               for name, _, _ in latent_stacks(cfg)}
+        if cfg.moe is not None:
+            out["moe_counts"] = (None,)
+        return out
     return _stackN(attn_mod.kv_cache_axes(), "layers")
 
 

@@ -68,14 +68,19 @@ def count_params_analytic(cfg: ModelConfig) -> int:
 
 
 def active_params_analytic(cfg: ModelConfig) -> int:
-    """MoE: parameters touched per token (for 6·N_active·D roofline FLOPs)."""
+    """MoE: parameters touched per token (for 6·N_active·D roofline FLOPs).
+    Of the experts held here, a token touches ``top_k`` times the share of
+    the router's experts they are; shared experts, latent attention and
+    leading dense layers are all touched."""
     total = count_params_analytic(cfg)
     if cfg.moe is None:
         return total
-    e, k = cfg.moe.n_experts, cfg.moe.top_k
-    per_expert = 3 * cfg.d_model * cfg.moe.d_ff
-    expert_total = cfg.n_layers * e * per_expert
-    expert_active = cfg.n_layers * k * per_expert
+    moe = cfg.moe
+    per_expert = 3 * cfg.d_model * moe.d_ff
+    moe_layers = cfg.n_layers - cfg.n_dense_layers
+    expert_total = moe_layers * moe.n_experts * per_expert
+    expert_active = (moe_layers * moe.top_k * per_expert * moe.n_experts
+                     // moe.n_routed)
     return total - expert_total + expert_active
 
 
@@ -164,6 +169,8 @@ class Model:
             return tfm.init_xlstm_cache(cfg, batch_size, dtype)
         if cfg.family == "hybrid":
             return tfm.init_zamba_cache(cfg, batch_size, max_len, dtype)
+        if cfg.mla is not None:
+            return tfm.init_latent_cache(cfg, batch_size, max_len, dtype)
         cache = attn_mod.init_kv_cache(cfg, batch_size, max_len, dtype)
         return jax.tree_util.tree_map(
             lambda x: jnp.broadcast_to(x, (cfg.n_layers,) + x.shape), cache

@@ -1,4 +1,12 @@
-"""Mixture-of-Experts: top-k routing with grouped, capacity-bounded dispatch.
+"""Mixture-of-Experts: two layers.
+
+* The capacity layer (mixtral, qwen3; below): top-k routing with grouped,
+  capacity-bounded dispatch.
+* The dropless expert-parallel layer (the latent-attention stack; at the
+  end of this file): shared experts beside routed ones, of which it holds a
+  share, with grouped (ragged) matmuls over the pairs routed to them.
+
+The capacity layer:
 
 TPU-native (GShard-style) formulation: tokens are split into **groups** (the
 group dim shards over the data axes); each group independently builds a small
@@ -19,6 +27,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.models import layers as L
 from repro.models.layers import ParamSpec
 from repro.parallel.sharding import shard
 
@@ -146,3 +155,116 @@ def apply_moe(
         "gtec,gecd->gtd", combine.astype(x.dtype), expert_out
     )
     return out.reshape(B, S, D), aux
+
+
+# ---------------------------------------------------------------------------
+# Dropless expert-parallel layer (DeepSeek-V3 style: shared + routed experts)
+# ---------------------------------------------------------------------------
+#
+# The layer is told which experts it holds: ``n_experts`` of the router's
+# ``router_experts``, from index ``first_expert``.  It routes every token
+# over all of them and computes only its own experts' part of the result:
+# the (token, expert) pairs that fall on a held expert, sorted by expert and
+# run through grouped (ragged) matmuls, so that no assignment is dropped.
+# What the other experts would add is computed where they are held; on one
+# chip the layer runs without the exchange that would bring it.
+
+#: tokens dispatched at a time; a larger batch (a prefill) goes through in
+#: chunks, which bounds the sorted copy of the tokens at chunk x top_k rows
+DROPLESS_CHUNK = 8192
+
+
+def init_moe_ep(cfg: ModelConfig):
+    moe = cfg.moe
+    d, f, e, r = cfg.d_model, moe.d_ff, moe.n_experts, moe.n_routed
+    spec = {
+        "router": {
+            "w": ParamSpec((d, r), ("embed", None), scale=1.0),
+            # the correction bias of noaux_tc routing: it moves which
+            # experts are chosen, never their weights
+            "bias": ParamSpec((r,), (None,), init="zeros"),
+        },
+        "experts": {
+            "gate": ParamSpec((e, d, f), ("experts", "embed", "mlp")),
+            "up": ParamSpec((e, d, f), ("experts", "embed", "mlp")),
+            "down": ParamSpec((e, f, d), ("experts", "mlp", "embed")),
+        },
+    }
+    if moe.shared_d_ff:
+        spec["shared"] = L.init_mlp(d, moe.shared_d_ff, "swiglu")
+    return spec
+
+
+def route(params, cfg: ModelConfig, x):
+    """Top-k experts ``[T, k]`` (router indices) of tokens ``x`` ``[T, D]``
+    and their combine weights ``[T, k]`` (float32): chosen by score plus
+    the correction bias, weighted by the unbiased score, renormalised over
+    the top k where ``norm_topk``, times ``routed_scale``."""
+    moe = cfg.moe
+    logits = x.astype(jnp.float32) @ params["router"]["w"].astype(jnp.float32)
+    if moe.score_func == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    else:
+        scores = jax.nn.softmax(logits, axis=-1)
+    choice = scores + params["router"]["bias"].astype(jnp.float32)
+    _, idx = jax.lax.top_k(choice, moe.top_k)
+    weights = jnp.take_along_axis(scores, idx, axis=-1)
+    if moe.norm_topk:
+        weights = weights / (jnp.sum(weights, -1, keepdims=True) + 1e-20)
+    return idx, weights * moe.routed_scale
+
+
+def _held_experts(params, cfg: ModelConfig, x, idx, weights):
+    """This chip's experts' part of the output for tokens ``x`` ``[T, D]``
+    and the number of pairs routed to each held expert ``[E]``."""
+    moe = cfg.moe
+    t, d = x.shape
+    e, k = moe.n_experts, moe.top_k
+    local = idx - moe.first_expert
+    held = (local >= 0) & (local < e)
+    # pairs on experts held elsewhere sort last, past every group
+    slot = jnp.where(held, local, e).reshape(-1)
+    order = jnp.argsort(slot, stable=True)
+    sizes = jnp.zeros((e + 1,), jnp.int32).at[slot].add(1)[:e]
+    token = order // k
+    xs = x[token]
+    w = params["experts"]
+    gate = jax.lax.ragged_dot(xs, w["gate"].astype(x.dtype), sizes)
+    up = jax.lax.ragged_dot(xs, w["up"].astype(x.dtype), sizes)
+    y = jax.lax.ragged_dot(jax.nn.silu(gate) * up,
+                           w["down"].astype(x.dtype), sizes)
+    mine = jnp.arange(t * k) < jnp.sum(sizes)
+    y = jnp.where(mine[:, None],
+                  y.astype(jnp.float32) * weights.reshape(-1)[order][:, None],
+                  0.0)
+    return jnp.zeros((t, d), jnp.float32).at[token].add(y), sizes
+
+
+def apply_moe_ep(params, cfg: ModelConfig, x):
+    """The layer on ``x`` ``[B, S, D]``.  Returns the output ``[B, S, D]``
+    and, as int32 ``[2]``, the (token, held expert) pairs routed here and
+    the held experts that got at least one."""
+    b, s, d = x.shape
+    t = b * s
+    chunks = group_tokens(t, DROPLESS_CHUNK)
+    xc = x.reshape(chunks, t // chunks, d)
+
+    def one_chunk(xt):
+        with jax.named_scope("moe.route"):
+            idx, weights = route(params, cfg, xt)
+        with jax.named_scope("moe.experts"):
+            return _held_experts(params, cfg, xt, idx, weights)
+
+    if chunks == 1:
+        out, sizes = one_chunk(xc[0])
+        out, sizes = out[None], sizes[None]
+    else:
+        out, sizes = jax.lax.map(one_chunk, xc)
+    out = out.reshape(b, s, d)
+    if "shared" in params:
+        with jax.named_scope("moe.shared"):
+            out = out + L.apply_mlp(params["shared"], x, "swiglu").astype(
+                jnp.float32)
+    sizes = jnp.sum(sizes, axis=0)
+    counts = jnp.stack([jnp.sum(sizes), jnp.sum(sizes > 0)]).astype(jnp.int32)
+    return out.astype(x.dtype), counts

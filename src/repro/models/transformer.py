@@ -7,7 +7,10 @@ and makes remat policies uniform.  Heterogeneous stacks are block-structured:
 
 * zamba2: 13 super-blocks of (6 Mamba2 layers + 1 shared-attention
   application with per-application LoRA) + a 3-layer Mamba tail;
-* xlstm:  6 super-blocks of (7 mLSTM + 1 sLSTM).
+* xlstm:  6 super-blocks of (7 mLSTM + 1 sLSTM);
+* latent attention (moonlight, the DeepSeek-V3 block): ``n_dense_layers``
+  leading dense layers, then the scanned MoE stack, each layer with
+  multi-head latent attention and its latent cache.
 """
 
 from __future__ import annotations
@@ -20,6 +23,7 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.models import attention as attn
 from repro.models import layers as L
+from repro.models import mla as mla_mod
 from repro.models import moe as moe_mod
 from repro.models import ssm as ssm_mod
 from repro.models import xlstm as xlstm_mod
@@ -171,6 +175,8 @@ def lm_logits(params, cfg: ModelConfig, x):
 
 
 def init_lm(cfg: ModelConfig):
+    if cfg.mla is not None:
+        return init_latent_lm(cfg)
     spec = init_lm_shell(cfg)
     spec["blocks"] = stack_specs(init_block(cfg), cfg.n_layers)
     return spec
@@ -183,6 +189,8 @@ def forward_lm(
     pixel_embeds: jnp.ndarray | None = None,
 ):
     """Training/eval forward.  Returns (logits, aux_loss)."""
+    if cfg.mla is not None:
+        return forward_latent_lm(params, cfg, tokens)
     x = embed_tokens(params, cfg, tokens, pixel_embeds)
     positions = jnp.arange(tokens.shape[1])
 
@@ -201,6 +209,8 @@ def forward_lm(
 def prefill_lm(params, cfg: ModelConfig, tokens, max_len: int,
                pixel_embeds=None):
     """Prefill: forward + build the KV cache.  Returns (logits, cache)."""
+    if cfg.mla is not None:
+        return prefill_latent_lm(params, cfg, tokens, max_len)
     B, S = tokens.shape
     x = embed_tokens(params, cfg, tokens, pixel_embeds)
     positions = jnp.arange(S)
@@ -221,6 +231,8 @@ def prefill_lm(params, cfg: ModelConfig, tokens, max_len: int,
 
 def decode_lm(params, cfg: ModelConfig, tokens_new, caches, position):
     """One decode step.  tokens_new [B,1]; caches stacked [L,...]."""
+    if cfg.mla is not None:
+        return decode_latent_lm(params, cfg, tokens_new, caches, position)
     x = embed_tokens(params, cfg, tokens_new)
     positions = jnp.full((tokens_new.shape[0], 1), position, jnp.int32)
 
@@ -233,6 +245,131 @@ def decode_lm(params, cfg: ModelConfig, tokens_new, caches, position):
         return x, new_cache
 
     x, new_caches = scan_layers(cfg, body, x, (params["blocks"], caches))
+    return lm_logits(params, cfg, x), new_caches
+
+
+# ---------------------------------------------------------------------------
+# Latent-attention LM: leading dense layers, then the scanned MoE stack
+# ---------------------------------------------------------------------------
+#
+# Parameters and caches hold one stacked tree per stack: ``dense_blocks``
+# (the leading dense layers, where there are any) and ``blocks``.  With
+# experts, the cache also holds ``moe_counts`` (int32 [2]): the (token, held
+# expert) pairs and the held experts hit, summed over the layers of every
+# decode step since the prefill, which leaves it at zero.
+
+
+def latent_stacks(cfg: ModelConfig) -> list:
+    """``(name, layers, dense)`` of each scanned stack, in order."""
+    stacks = []
+    if cfg.n_dense_layers:
+        stacks.append(("dense_blocks", cfg.n_dense_layers, True))
+    stacks.append(("blocks", cfg.n_layers - cfg.n_dense_layers,
+                   cfg.moe is None))
+    return stacks
+
+
+def init_latent_block(cfg: ModelConfig, dense: bool):
+    spec = {
+        "attn_norm": L.init_norm(cfg.d_model, cfg.norm_type),
+        "attn": mla_mod.init_mla(cfg),
+        "mlp_norm": L.init_norm(cfg.d_model, cfg.norm_type),
+    }
+    if dense:
+        spec["mlp"] = L.init_mlp(cfg.d_model, cfg.d_ff, cfg.mlp_type)
+    else:
+        spec["moe"] = moe_mod.init_moe_ep(cfg)
+    return spec
+
+
+def init_latent_lm(cfg: ModelConfig):
+    spec = init_lm_shell(cfg)
+    for name, n, dense in latent_stacks(cfg):
+        spec[name] = stack_specs(init_latent_block(cfg, dense), n)
+    return spec
+
+
+def init_latent_cache(cfg: ModelConfig, batch: int, max_len: int, dtype):
+    one = mla_mod.init_cache(cfg, batch, max_len, dtype)
+    cache = {name: jax.tree_util.tree_map(
+        lambda a, n=n: jnp.broadcast_to(a, (n,) + a.shape), one)
+        for name, n, _ in latent_stacks(cfg)}
+    if cfg.moe is not None:
+        cache["moe_counts"] = jnp.zeros((2,), jnp.int32)
+    return cache
+
+
+def apply_latent_block(params, cfg: ModelConfig, x, positions, *,
+                       cache=None, position=None, max_len=None):
+    """One layer; the attention's modes are ``mla.apply_mla``'s.  Returns
+    (x, cache, counts): counts as ``moe.apply_moe_ep`` gives them, zero for
+    a dense layer."""
+    h = L.apply_norm(params["attn_norm"], x, cfg.norm_type, cfg.norm_eps)
+    out, cache = mla_mod.apply_mla(params["attn"], cfg, h, positions,
+                                   cache=cache, position=position,
+                                   max_len=max_len)
+    x = x + out
+    h = L.apply_norm(params["mlp_norm"], x, cfg.norm_type, cfg.norm_eps)
+    if "moe" in params:
+        out, counts = moe_mod.apply_moe_ep(params["moe"], cfg, h)
+    else:
+        out = L.apply_mlp(params["mlp"], h, cfg.mlp_type)
+        counts = jnp.zeros((2,), jnp.int32)
+    x = shard(x + out, "batch", "seq", "embed")
+    return x, cache, counts
+
+
+def forward_latent_lm(params, cfg: ModelConfig, tokens):
+    x = embed_tokens(params, cfg, tokens)
+    positions = jnp.arange(tokens.shape[1])
+
+    def body(x, block_params):
+        x, _, _ = apply_latent_block(block_params, cfg, x, positions)
+        return x, None
+
+    for name, _, _ in latent_stacks(cfg):
+        x, _ = scan_layers(cfg, _remat(body, cfg), x, params[name])
+    # the dropless layer has no auxiliary loss (noaux_tc routing)
+    return lm_logits(params, cfg, x), jnp.zeros((), jnp.float32)
+
+
+def prefill_latent_lm(params, cfg: ModelConfig, tokens, max_len: int):
+    x = embed_tokens(params, cfg, tokens)
+    positions = jnp.arange(tokens.shape[1])
+
+    def body(x, block_params):
+        x, cache, _ = apply_latent_block(block_params, cfg, x, positions,
+                                         max_len=max_len)
+        return x, cache
+
+    caches = {}
+    for name, _, _ in latent_stacks(cfg):
+        x, caches[name] = scan_layers(cfg, _remat(body, cfg), x,
+                                      params[name])
+    if cfg.moe is not None:
+        caches["moe_counts"] = jnp.zeros((2,), jnp.int32)
+    return lm_logits(params, cfg, x[:, -1:, :]), caches
+
+
+def decode_latent_lm(params, cfg: ModelConfig, tokens_new, caches, position):
+    x = embed_tokens(params, cfg, tokens_new)
+    positions = jnp.full((tokens_new.shape[0], 1), position, jnp.int32)
+
+    def body(carry, xs):
+        x, counts = carry
+        block_params, cache = xs
+        x, cache, c = apply_latent_block(block_params, cfg, x, positions,
+                                         cache=cache, position=position)
+        return (x, counts + c), cache
+
+    carry = (x, jnp.zeros((2,), jnp.int32))
+    new_caches = {}
+    for name, _, _ in latent_stacks(cfg):
+        carry, new_caches[name] = scan_layers(
+            cfg, body, carry, (params[name], caches[name]))
+    x, counts = carry
+    if "moe_counts" in caches:
+        new_caches["moe_counts"] = caches["moe_counts"] + counts
     return lm_logits(params, cfg, x), new_caches
 
 

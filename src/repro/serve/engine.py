@@ -135,7 +135,23 @@ class ServeEngine:
                 cur = np.asarray(self._sample(logits))
         generated = np.stack(out, axis=1) if out else np.zeros((B, 0), np.int32)
         self.stats["tokens_generated"] += int(generated.size)
+        # every token after the first came from one decode step
+        self._count_experts(cache, max(len(out) - 1, 0))
         return {"tokens": generated, "prompt_len": S, "logits": kept_logits}
+
+    def _count_experts(self, cache, steps: int) -> None:
+        """Add the expert layers' counter, which the decode steps sum on
+        the device in the cache, to ``stats``, pulled once per call:
+        ``moe_routed_pairs`` (token, held expert) pairs and
+        ``moe_experts_hit`` held experts with at least one, each summed
+        over layers and ``moe_decode_steps`` steps."""
+        if not isinstance(cache, dict) or "moe_counts" not in cache:
+            return
+        routed, hit = (int(n) for n in np.asarray(cache["moe_counts"]))
+        for key, n in (("moe_decode_steps", steps),
+                       ("moe_routed_pairs", routed),
+                       ("moe_experts_hit", hit)):
+            self.stats[key] = self.stats.get(key, 0) + n
 
 
 class BatchAccumulator:

@@ -26,7 +26,8 @@ def _mesh():
 
 @pytest.mark.parametrize("arch", ["internlm2-1.8b", "mixtral-8x7b",
                                   "xlstm-1.3b", "zamba2-7b",
-                                  "whisper-medium", "internvl2-2b"])
+                                  "whisper-medium", "internvl2-2b",
+                                  "moonlight-16b-a3b"])
 def test_train_cell_lowers_and_compiles(arch):
     cfg = configs.get(arch, smoke=True)
     mesh = _mesh()
@@ -51,7 +52,8 @@ def test_train_cell_lowers_and_compiles(arch):
     assert "_total" in stats
 
 
-@pytest.mark.parametrize("arch", ["internlm2-1.8b", "whisper-medium"])
+@pytest.mark.parametrize("arch", ["internlm2-1.8b", "whisper-medium",
+                                  "moonlight-16b-a3b"])
 def test_decode_cell_lowers_and_compiles(arch):
     cfg = configs.get(arch, smoke=True)
     mesh = _mesh()

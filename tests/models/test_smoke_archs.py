@@ -94,5 +94,6 @@ def test_full_config_param_count_scale(arch):
         "zamba2-7b": 7e9,
         "whisper-medium": 0.77e9,
         "internvl2-2b": 1.9e9,  # LM backbone only (ViT is stubbed)
+        "moonlight-16b-a3b": 16e9,
     }[arch]
     assert 0.5 * nominal < n < 1.7 * nominal, f"{arch}: {n/1e9:.2f}B params"
