@@ -52,3 +52,39 @@ def prefill_mfu(run, cell) -> float | None:
                for _, s, b in shaped)
     busy = sum(p[1] - p[0] for p, _, _ in shaped)
     return 100.0 * work / (busy * peaks(run.device_kind).bf16_flops)
+
+
+#: the named scopes of the latent-attention stack, by the first part of an
+#: ``op_name`` path that names one.  The TPU's kernels for
+#: ``jax.lax.ragged_dot`` (the held experts' matmuls) lose the scope: their
+#: ``op_name`` is the kernel's own (``ragged-dot-none``), and only the
+#: expert layer calls ``ragged_dot``
+SCOPES = {"mla": lambda part: part == "mla",
+          "moe": lambda part: part.startswith(("moe.", "ragged-dot"))}
+
+
+def scope_hbm_share(run, cell, label: str) -> float | None:
+    """Least bytes of a decode step's work under scope ``label`` (``mla``:
+    every layer's attention weights and latent cache up to the mix's mean
+    position; ``moe``: routers, shared experts and the held experts at the
+    counter's hit share) over the median device time of the decode
+    program's operations under that scope at the chip's HBM bandwidth,
+    percent."""
+    from benchmarks.chip import scopes
+
+    mix, cfg = cell.traffic, cell.model
+    if label == "mla":
+        least = flops_mla_moe.mla_least_bytes(cfg, mix["batch"],
+                                              mean_position(mix))
+    else:
+        share = flops_mla_moe.hit_share(run.data.get("engine_stats", {}),
+                                        cfg)
+        if share is None:
+            return None
+        least = flops_mla_moe.moe_least_bytes(cfg, share)
+    times = [t[label] for t in scopes.scope_seconds(run, cell, DECODE, SCOPES)
+             if t.get(label)]
+    if not times:
+        return None
+    t = xplane.median(times)
+    return 100.0 * least / (t * peaks(run.device_kind).hbm_bytes_per_s)

@@ -176,6 +176,9 @@ DROPLESS_CHUNK = 8192
 
 def init_moe_ep(cfg: ModelConfig):
     moe = cfg.moe
+    if moe.score_func != "sigmoid" or not moe.norm_topk:
+        raise ValueError("the dropless layer scores with a sigmoid and "
+                         "renormalises the top k (DeepSeek-V3 routing)")
     d, f, e, r = cfg.d_model, moe.d_ff, moe.n_experts, moe.n_routed
     spec = {
         "router": {
@@ -197,20 +200,16 @@ def init_moe_ep(cfg: ModelConfig):
 
 def route(params, cfg: ModelConfig, x):
     """Top-k experts ``[T, k]`` (router indices) of tokens ``x`` ``[T, D]``
-    and their combine weights ``[T, k]`` (float32): chosen by score plus
-    the correction bias, weighted by the unbiased score, renormalised over
-    the top k where ``norm_topk``, times ``routed_scale``."""
+    and their combine weights ``[T, k]`` (float32): chosen by sigmoid score
+    plus the correction bias, weighted by the unbiased score, renormalised
+    over the top k, times ``routed_scale``."""
     moe = cfg.moe
     logits = x.astype(jnp.float32) @ params["router"]["w"].astype(jnp.float32)
-    if moe.score_func == "sigmoid":
-        scores = jax.nn.sigmoid(logits)
-    else:
-        scores = jax.nn.softmax(logits, axis=-1)
+    scores = jax.nn.sigmoid(logits)
     choice = scores + params["router"]["bias"].astype(jnp.float32)
     _, idx = jax.lax.top_k(choice, moe.top_k)
     weights = jnp.take_along_axis(scores, idx, axis=-1)
-    if moe.norm_topk:
-        weights = weights / (jnp.sum(weights, -1, keepdims=True) + 1e-20)
+    weights = weights / (jnp.sum(weights, -1, keepdims=True) + 1e-20)
     return idx, weights * moe.routed_scale
 
 
