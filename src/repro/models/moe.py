@@ -4,7 +4,8 @@
   capacity-bounded dispatch.
 * The dropless expert-parallel layer (the latent-attention stack; at the
   end of this file): shared experts beside routed ones, of which it holds a
-  share, with grouped (ragged) matmuls over the pairs routed to them.
+  share, computed densely on a few tokens or with grouped (ragged) matmuls
+  over the pairs routed to them.
 
 The capacity layer:
 
@@ -163,15 +164,35 @@ def apply_moe(
 #
 # The layer is told which experts it holds: ``n_experts`` of the router's
 # ``router_experts``, from index ``first_expert``.  It routes every token
-# over all of them and computes only its own experts' part of the result:
-# the (token, expert) pairs that fall on a held expert, sorted by expert and
-# run through grouped (ragged) matmuls, so that no assignment is dropped.
+# over all of them and computes only its own experts' part of the result,
+# so that no assignment is dropped, in one of two ways chosen by the
+# call's token count T:
+#
+# * T <= DENSE_TOKENS (a decode step): every held expert on every token,
+#   with plain dots that read the stacked expert weights in place, and the
+#   (token, expert) pairs the router did not choose masked out of the
+#   combine;
+# * otherwise (a prefill): the pairs that fall on a held expert, sorted by
+#   expert and run through grouped (ragged) matmuls.
+#
 # What the other experts would add is computed where they are held; on one
 # chip the layer runs without the exchange that would bring it.
 
 #: tokens dispatched at a time; a larger batch (a prefill) goes through in
 #: chunks, which bounds the sorted copy of the tokens at chunk x top_k rows
 DROPLESS_CHUNK = 8192
+
+#: the most tokens a call computes densely.  A token costs a held expert
+#: 6 d f FLOPs and the expert's weights are 6 d f bytes in bf16, so up to
+#: the chip's FLOPs per HBM byte (197e12 / 819e9 ~ 240 on a v5e) all held
+#: experts on all tokens take no longer than streaming their weights once,
+#: the least any path can do.  The ragged matmul is a kernel that takes its
+#: weights whole, so inside the layer scan each layer's held experts are
+#: first copied out of the stacked weights; a plain dot reads its slice.
+DENSE_TOKENS = 256
+
+#: entries of the layer's int32 counts (``apply_moe_ep``)
+MOE_COUNTS = 3
 
 
 def init_moe_ep(cfg: ModelConfig):
@@ -213,25 +234,41 @@ def route(params, cfg: ModelConfig, x):
     return idx, weights * moe.routed_scale
 
 
-def _held_experts(params, cfg: ModelConfig, x, idx, weights):
+def _held_experts(params, cfg: ModelConfig, x, idx, weights, dense: bool):
     """This chip's experts' part of the output for tokens ``x`` ``[T, D]``
-    and the number of pairs routed to each held expert ``[E]``."""
+    and the number of pairs routed to each held expert ``[E]``: every held
+    expert on every token where ``dense``, else the routed pairs through
+    ragged matmuls."""
     moe = cfg.moe
     t, d = x.shape
     e, k = moe.n_experts, moe.top_k
     local = idx - moe.first_expert
     held = (local >= 0) & (local < e)
-    # pairs on experts held elsewhere sort last, past every group
+    # pairs on experts held elsewhere take slot e: past every group, so
+    # the ragged path sorts them last
     slot = jnp.where(held, local, e).reshape(-1)
-    order = jnp.argsort(slot, stable=True)
     sizes = jnp.zeros((e + 1,), jnp.int32).at[slot].add(1)[:e]
+    w = params["experts"]
+    w_gate, w_up, w_down = (w[n].astype(x.dtype) for n in ("gate", "up",
+                                                            "down"))
+    if dense:
+        # pick[t, j, e]: token t's j-th choice is held expert e
+        pick = local[:, :, None] == jnp.arange(e)
+        routed = jnp.any(pick, axis=1).T                        # [E, T]
+        w_te = jnp.sum(jnp.where(pick, weights[:, :, None], 0.0), axis=1).T
+        gate = jnp.einsum("td,edf->etf", x, w_gate)
+        up = jnp.einsum("td,edf->etf", x, w_up)
+        y = jnp.einsum("etf,efd->etd", jax.nn.silu(gate) * up, w_down)
+        # a product the router never chose cannot reach the output
+        y = jnp.where(routed[:, :, None],
+                      w_te[:, :, None] * y.astype(jnp.float32), 0.0)
+        return jnp.sum(y, axis=0), sizes
+    order = jnp.argsort(slot, stable=True)
     token = order // k
     xs = x[token]
-    w = params["experts"]
-    gate = jax.lax.ragged_dot(xs, w["gate"].astype(x.dtype), sizes)
-    up = jax.lax.ragged_dot(xs, w["up"].astype(x.dtype), sizes)
-    y = jax.lax.ragged_dot(jax.nn.silu(gate) * up,
-                           w["down"].astype(x.dtype), sizes)
+    gate = jax.lax.ragged_dot(xs, w_gate, sizes)
+    up = jax.lax.ragged_dot(xs, w_up, sizes)
+    y = jax.lax.ragged_dot(jax.nn.silu(gate) * up, w_down, sizes)
     mine = jnp.arange(t * k) < jnp.sum(sizes)
     y = jnp.where(mine[:, None],
                   y.astype(jnp.float32) * weights.reshape(-1)[order][:, None],
@@ -241,10 +278,12 @@ def _held_experts(params, cfg: ModelConfig, x, idx, weights):
 
 def apply_moe_ep(params, cfg: ModelConfig, x):
     """The layer on ``x`` ``[B, S, D]``.  Returns the output ``[B, S, D]``
-    and, as int32 ``[2]``, the (token, held expert) pairs routed here and
-    the held experts that got at least one."""
+    and, as int32 ``[MOE_COUNTS]``, the (token, held expert) pairs routed
+    here, the held experts that got at least one, and whether the held
+    experts were computed densely (1) or with ragged matmuls (0)."""
     b, s, d = x.shape
     t = b * s
+    dense = t <= DENSE_TOKENS
     chunks = group_tokens(t, DROPLESS_CHUNK)
     xc = x.reshape(chunks, t // chunks, d)
 
@@ -252,7 +291,7 @@ def apply_moe_ep(params, cfg: ModelConfig, x):
         with jax.named_scope("moe.route"):
             idx, weights = route(params, cfg, xt)
         with jax.named_scope("moe.experts"):
-            return _held_experts(params, cfg, xt, idx, weights)
+            return _held_experts(params, cfg, xt, idx, weights, dense)
 
     if chunks == 1:
         out, sizes = one_chunk(xc[0])
@@ -265,5 +304,6 @@ def apply_moe_ep(params, cfg: ModelConfig, x):
             out = out + L.apply_mlp(params["shared"], x, "swiglu").astype(
                 jnp.float32)
     sizes = jnp.sum(sizes, axis=0)
-    counts = jnp.stack([jnp.sum(sizes), jnp.sum(sizes > 0)]).astype(jnp.int32)
+    counts = jnp.stack([jnp.sum(sizes), jnp.sum(sizes > 0),
+                        jnp.asarray(int(dense))]).astype(jnp.int32)
     return out.astype(x.dtype), counts

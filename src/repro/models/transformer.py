@@ -254,8 +254,9 @@ def decode_lm(params, cfg: ModelConfig, tokens_new, caches, position):
 #
 # Parameters and caches hold one stacked tree per stack: ``dense_blocks``
 # (the leading dense layers, where there are any) and ``blocks``.  With
-# experts, the cache also holds ``moe_counts`` (int32 [2]): the (token, held
-# expert) pairs and the held experts hit, summed over the layers of every
+# experts, the cache also holds ``moe_counts`` (int32 [MOE_COUNTS]): the
+# (token, held expert) pairs, the held experts hit and the expert layers
+# that computed their held experts densely, summed over the layers of every
 # decode step since the prefill, which leaves it at zero.
 
 
@@ -295,7 +296,7 @@ def init_latent_cache(cfg: ModelConfig, batch: int, max_len: int, dtype):
         lambda a, n=n: jnp.broadcast_to(a, (n,) + a.shape), one)
         for name, n, _ in latent_stacks(cfg)}
     if cfg.moe is not None:
-        cache["moe_counts"] = jnp.zeros((2,), jnp.int32)
+        cache["moe_counts"] = jnp.zeros((moe_mod.MOE_COUNTS,), jnp.int32)
     return cache
 
 
@@ -314,7 +315,7 @@ def apply_latent_block(params, cfg: ModelConfig, x, positions, *,
         out, counts = moe_mod.apply_moe_ep(params["moe"], cfg, h)
     else:
         out = L.apply_mlp(params["mlp"], h, cfg.mlp_type)
-        counts = jnp.zeros((2,), jnp.int32)
+        counts = jnp.zeros((moe_mod.MOE_COUNTS,), jnp.int32)
     x = shard(x + out, "batch", "seq", "embed")
     return x, cache, counts
 
@@ -347,7 +348,7 @@ def prefill_latent_lm(params, cfg: ModelConfig, tokens, max_len: int):
         x, caches[name] = scan_layers(cfg, _remat(body, cfg), x,
                                       params[name])
     if cfg.moe is not None:
-        caches["moe_counts"] = jnp.zeros((2,), jnp.int32)
+        caches["moe_counts"] = jnp.zeros((moe_mod.MOE_COUNTS,), jnp.int32)
     return lm_logits(params, cfg, x[:, -1:, :]), caches
 
 
@@ -362,7 +363,7 @@ def decode_latent_lm(params, cfg: ModelConfig, tokens_new, caches, position):
                                          cache=cache, position=position)
         return (x, counts + c), cache
 
-    carry = (x, jnp.zeros((2,), jnp.int32))
+    carry = (x, jnp.zeros((moe_mod.MOE_COUNTS,), jnp.int32))
     new_caches = {}
     for name, _, _ in latent_stacks(cfg):
         carry, new_caches[name] = scan_layers(

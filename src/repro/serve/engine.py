@@ -142,15 +142,17 @@ class ServeEngine:
     def _count_experts(self, cache, steps: int) -> None:
         """Add the expert layers' counter, which the decode steps sum on
         the device in the cache, to ``stats``, pulled once per call:
-        ``moe_routed_pairs`` (token, held expert) pairs and
-        ``moe_experts_hit`` held experts with at least one, each summed
-        over layers and ``moe_decode_steps`` steps."""
+        ``moe_routed_pairs`` (token, held expert) pairs,
+        ``moe_experts_hit`` held experts with at least one and
+        ``moe_dense_calls`` expert layers that computed their held experts
+        densely, each summed over layers and ``moe_decode_steps`` steps."""
         if not isinstance(cache, dict) or "moe_counts" not in cache:
             return
-        routed, hit = (int(n) for n in np.asarray(cache["moe_counts"]))
+        routed, hit, dense = (int(n) for n in np.asarray(cache["moe_counts"]))
         for key, n in (("moe_decode_steps", steps),
                        ("moe_routed_pairs", routed),
-                       ("moe_experts_hit", hit)):
+                       ("moe_experts_hit", hit),
+                       ("moe_dense_calls", dense)):
             self.stats[key] = self.stats.get(key, 0) + n
 
 

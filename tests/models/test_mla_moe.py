@@ -5,7 +5,8 @@ small size in float32 on the CPU, on the weights the benchmark makes.
 Latent attention: the program's forward, its prefill then decode through
 the latent cache, and its absorbed decode against the expanded path.  The
 expert-parallel layer: the shares of the experts add up to the uncut
-layer, no assignment is dropped when every token picks one expert, and the
+layer and no assignment is dropped when every token picks one expert, on
+the dense path (a few tokens) and the ragged one, which agree; and the
 correction bias moves the choice but never the weights.
 """
 
@@ -144,14 +145,21 @@ def _moe_params(cfg: dict, seed: int):
     return jax.tree_util.tree_map(lambda a: a[0], full["blocks"]["moe"])
 
 
-def test_expert_shares_add_up_to_the_uncut_layer():
+#: a call of 14 or 64 tokens computes its held experts densely, one of 320
+#: (over ``moe.DENSE_TOKENS``) with ragged matmuls
+RAGGED = (4, 80, 0)
+
+
+@pytest.mark.parametrize("b,s,dense", [(2, 7, 1), RAGGED],
+                         ids=["dense", "ragged"])
+def test_expert_shares_add_up_to_the_uncut_layer(b, s, dense):
     """Eight chips each hold 8 of 64 experts: the parts the eight shares
     give, with the shared experts (which every chip computes) counted
     once, add up to what the uncut reference gives for the whole layer."""
     _, uncut_dict = _layer_cfg(n_experts=64, router_experts=64, top_k=6,
                                first_expert=0)
     uncut = _moe_params(uncut_dict, SEED)
-    x = jax.random.normal(jax.random.PRNGKey(5), (2, 7, TINY["d_model"]))
+    x = jax.random.normal(jax.random.PRNGKey(5), (b, s, TINY["d_model"]))
     with jax.default_matmul_precision("highest"):
         want = ref.experts(uncut_dict, uncut, x, ref.exact)
         total, counts = 0.0, 0
@@ -164,18 +172,22 @@ def test_expert_shares_add_up_to_the_uncut_layer():
             out, c = moe.apply_moe_ep(share, cfg, x)
             total = total + out
             counts += int(c[0])
+            assert int(c[2]) == dense
         shared = ref.swiglu(uncut["shared"], x, ref.exact)
     np.testing.assert_allclose(np.asarray(total - 7 * shared),
                                np.asarray(want), **TOL)
-    assert counts == 2 * 7 * 6      # every (token, expert) pair, once
+    assert counts == b * s * 6      # every (token, expert) pair, once
 
 
-def test_no_assignment_is_dropped_when_every_token_picks_one_expert():
+@pytest.mark.parametrize("b,s,dense", [(4, 16, 1), RAGGED],
+                         ids=["dense", "ragged"])
+def test_no_assignment_is_dropped_when_every_token_picks_one_expert(b, s,
+                                                                    dense):
     cfg, cfg_dict = _layer_cfg()
     params = _moe_params(cfg_dict, SEED + 1)
     # a bias that makes held expert 5 (router index) every token's choice
     params["router"]["bias"] = params["router"]["bias"].at[5].set(100.0)
-    x = jax.random.normal(jax.random.PRNGKey(6), (4, 16, TINY["d_model"]))
+    x = jax.random.normal(jax.random.PRNGKey(6), (b, s, TINY["d_model"]))
     with jax.default_matmul_precision("highest"):
         idx, _ = moe.route(params, cfg, x.reshape(-1, TINY["d_model"]))
         assert bool(jnp.all(jnp.any(idx == 5, axis=-1)))
@@ -185,6 +197,29 @@ def test_no_assignment_is_dropped_when_every_token_picks_one_expert():
     held = (idx >= 4) & (idx < 8)
     assert int(counts[0]) == int(jnp.sum(held))
     assert int(counts[1]) == len(np.unique(np.asarray(idx)[np.asarray(held)]))
+    assert int(counts[2]) == dense
+
+
+def test_both_paths_give_the_same_experts_output_and_sizes():
+    """The dense and the ragged path on one set of tokens, some routed to
+    no held expert: the same output, and the same pairs per held expert."""
+    cfg, cfg_dict = _layer_cfg()
+    params = _moe_params(cfg_dict, SEED + 3)
+    x = jax.random.normal(jax.random.PRNGKey(8), (48, TINY["d_model"]))
+    with jax.default_matmul_precision("highest"):
+        idx, weights = moe.route(params, cfg, x)
+        dense, dense_sizes = moe._held_experts(params, cfg, x, idx, weights,
+                                               dense=True)
+        ragged, ragged_sizes = moe._held_experts(params, cfg, x, idx,
+                                                 weights, dense=False)
+    held = np.asarray((idx >= 4) & (idx < 8))
+    assert 0 < held.any(axis=1).sum() < len(held)
+    np.testing.assert_allclose(np.asarray(dense), np.asarray(ragged), **TOL)
+    np.testing.assert_array_equal(np.asarray(dense_sizes),
+                                  np.asarray(ragged_sizes))
+    np.testing.assert_array_equal(
+        np.asarray(dense_sizes),
+        [(np.asarray(idx) == 4 + j).sum() for j in range(4)])
 
 
 def test_the_correction_bias_moves_the_choice_not_the_weights():
@@ -215,6 +250,8 @@ def test_the_engine_pulls_the_expert_counter_once_per_call(case):
     assert 0 < stats["moe_routed_pairs"] <= 4 * 12
     assert 0 < stats["moe_experts_hit"] <= min(4 * 8,
                                                stats["moe_routed_pairs"])
+    # a decode step's two rows take the dense path in both expert layers
+    assert stats["moe_dense_calls"] == 2 * 4
 
 
 def test_the_registry_config_counts_the_published_model():
